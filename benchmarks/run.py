@@ -87,6 +87,8 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.suite is not None:
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
         fn = _suite_fn(args.suite)
         try:
             for row in fn(smoke=args.smoke):
@@ -97,15 +99,13 @@ def main() -> None:
             raise SystemExit(1)
         return
 
-    # Each suite runs in its own subprocess.  jaxlib 0.4.37's CPU
-    # backend misbehaves once a few hundred compiled executables have
-    # accumulated in one process (low-bit result corruption, and
-    # eventually SIGSEGV — the same pathology tests/conftest.py works
-    # around), and jax.clear_caches() between suites does not reset the
-    # responsible process-global state.  Process isolation does: it
-    # keeps every suite's results bitwise-identical to a standalone
+    # Each suite runs in its own subprocess, so no compiled executable,
+    # cache or global JAX config of an earlier suite is live while it
+    # runs: every suite's results stay bitwise-identical to a standalone
     # run, which the bitwise gates (trace_bitwise_match,
-    # static_bitwise_match) depend on.
+    # static_bitwise_match) depend on, and one suite's crash does not
+    # end the others.  This parent never imports jax, so on a TPU host
+    # each child in turn gets the chip.
     import subprocess
 
     print("name,us_per_call,derived", flush=True)

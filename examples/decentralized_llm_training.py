@@ -6,8 +6,10 @@ agents with heterogeneous token streams — the full production code path:
 shard_map consensus (ppermute ring), Neumann hypergradients, per-agent
 heads, checkpointing.
 
-    PYTHONPATH=src python examples/decentralized_llm_training.py \
-        [--steps 300] [--agents 4]
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 PYTHONPATH=src \
+        python examples/decentralized_llm_training.py [--steps 300] [--agents 4]
+
+(one device per agent: on CPU the flag gives the host four).
 """
 import argparse
 import dataclasses
@@ -21,7 +23,6 @@ from repro.checkpoint.checkpoint import save_step
 from repro.configs import get_config
 from repro.data.synthetic import TokenTaskStream
 from repro.launch.train import make_host_mesh
-from repro.sharding.compat import set_mesh
 from repro.sharding.partition import tree_shardings
 from repro.train.bilevel_lm import BilevelHyper
 from repro.train.step import (
@@ -67,7 +68,7 @@ def main() -> None:
     evaluate = make_eval_step(cfg, mesh, icfg)
     tok_shard = NamedSharding(mesh, P("data"))
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep = jax.jit(step, donate_argnums=(0,))
         jeval = jax.jit(evaluate)
         eval_tokens = jax.device_put(

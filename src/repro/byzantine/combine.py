@@ -98,7 +98,9 @@ class WeightedRule(CombineRule):
 
     def aggregate(self, vals, support, matrix, trim):
         del support, trim
-        return matrix @ vals
+        # HIGHEST: bf16-rounded weights break the exact average
+        # (repro.core.consensus.mix_pytree)
+        return jnp.matmul(matrix, vals, precision=jax.lax.Precision.HIGHEST)
 
 
 @register_combine_rule("coordinate-median")

@@ -77,8 +77,10 @@ class AllGatherEngine(MeshBackendMixin, ConsensusEngine):
         gathered = self._gather(tree)
 
         def combine(g, l):
+            # HIGHEST: see repro.core.consensus.mix_pytree
             mixed = jnp.tensordot(rows, g.astype(jnp.float32),
-                                  axes=[[1], [0]])
+                                  axes=[[1], [0]],
+                                  precision=jax.lax.Precision.HIGHEST)
             return mixed.astype(l.dtype)
 
         return jax.tree_util.tree_map(combine, gathered, tree)

@@ -635,7 +635,7 @@ def make_engine(backend: str, mixing, **opts) -> ConsensusEngine:
     """Build a consensus backend by name.
 
     ``mixing`` is a ``MixingSpec`` or a raw (m, m) matrix.  Backend
-    options: ``block_d``/``interpret`` (pallas), ``agent_axes``/
+    options: ``interpret`` (pallas), ``agent_axes``/
     ``compress``/``dp_sigma`` (ppermute), ``agent_axes`` (allgather);
     every backend additionally accepts ``compression``/
     ``communication_interval``/``byzantine`` wire options.

@@ -6,7 +6,8 @@ Step-1/3 matmuls run in one launch with the (m, m) mixing matrix
 VMEM-resident and the flattened parameters streaming through once.
 Arbitrary pytrees are handled by ``ravel_pytree`` and D is zero-padded to
 the tile size inside the kernel, so any model / any dense ``M`` works.
-``interpret=True`` (default) executes the same kernel body on CPU.
+The kernel runs compiled on TPU and interpreted on CPU
+(``repro.kernels.resolve_interpret``); ``interpret=`` overrides that.
 """
 from __future__ import annotations
 
@@ -16,7 +17,6 @@ import jax.numpy as jnp
 from repro.consensus.compress import CompressionConfig
 from repro.consensus.engine import ConsensusEngine
 from repro.core.consensus import MixingSpec
-from repro.kernels.consensus_step.kernel import DEFAULT_BLOCK_D
 from repro.kernels.consensus_step.ops import consensus_mix, consensus_step
 
 __all__ = ["PallasEngine"]
@@ -27,21 +27,19 @@ class PallasEngine(ConsensusEngine):
     name = "pallas"
 
     def __init__(self, mixing: MixingSpec | jax.Array,
-                 block_d: int = DEFAULT_BLOCK_D, interpret: bool = True,
+                 interpret: bool | None = None,
                  compression: CompressionConfig | None = None,
                  communication_interval: int = 1, byzantine=None):
         mat = mixing.matrix if isinstance(mixing, MixingSpec) else mixing
         self.matrix = jnp.asarray(mat, jnp.float32)
-        self.block_d = int(block_d)
-        self.interpret = bool(interpret)
+        self.interpret = interpret
         self._configure_wire(compression, communication_interval, byzantine)
 
     def mix(self, tree, *, matrix=None, dp_key=None, agent_index=None):
         del dp_key, agent_index  # single-host backend: no wire, no DP
         mat = self.matrix if matrix is None else jnp.asarray(matrix,
                                                              jnp.float32)
-        return consensus_mix(mat, tree, block_d=self.block_d,
-                             interpret=self.interpret)
+        return consensus_mix(mat, tree, interpret=self.interpret)
 
     def step1_step3(self, x, u, p, p_prev, alpha, *, t=None, ef=None,
                     matrix=None, dp_key=None, agent_index=None):
@@ -67,5 +65,4 @@ class PallasEngine(ConsensusEngine):
         mat = self.matrix if matrix is None else jnp.asarray(matrix,
                                                              jnp.float32)
         return consensus_step(mat, x, u, p, p_prev,
-                              alpha=alpha_c, block_d=self.block_d,
-                              interpret=self.interpret)
+                              alpha=alpha_c, interpret=self.interpret)

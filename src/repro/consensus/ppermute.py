@@ -13,10 +13,9 @@ outgoing payload, ``dp_sigma > 0`` adds Gaussian noise to the payload
 whenever a ``dp_key`` is supplied to ``mix`` (the x-mix passes one, the
 u-mix does not — only shared iterates are privatized).
 
-``impl="psum"`` selects the all-reduce realisation of the same matrix —
-required for partially-auto bodies on old-JAX stacks whose partitioner
-cannot lower ppermute there (see sharding/compat); it needs the agent
-index threaded in via ``mix(..., agent_index=...)``.
+``impl="psum"`` selects the all-reduce realisation of the same matrix
+(one m-times-payload all-reduce instead of per-edge exchanges); it needs
+the agent index threaded in via ``mix(..., agent_index=...)``.
 """
 from __future__ import annotations
 

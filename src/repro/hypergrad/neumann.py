@@ -45,16 +45,13 @@ __all__ = [
 
 
 def neumann_truncated_apply(matvec: Callable, b, k_terms: int,
-                            lipschitz_g: float, *, unroll: bool = False,
-                            skip_last: bool = False):
+                            lipschitz_g: float, *, skip_last: bool = False):
     """(1/L) sum_{j<K} (I - H/L)^j b, counting executed HVPs.
 
     Returns ``(value, hvp_count)``.  ``skip_last`` omits the K-th HVP
     whose output the truncated sum discards (K-1 HVPs, same value — used
     by the linearized backend); the default keeps the seed's executed-op
-    sequence for bit-compatibility.  ``unroll`` replaces the
-    ``fori_loop`` with a python loop (old-JAX shard_map compatibility,
-    see repro/train/bilevel_lm.py).
+    sequence for bit-compatibility.
     """
     op = as_operator(matvec)
     L = lipschitz_g
@@ -70,14 +67,8 @@ def neumann_truncated_apply(matvec: Callable, b, k_terms: int,
         v = tree_sub(v, tree_scale(1.0 / L, hv))
         return v, acc, count
 
-    count0 = jnp.zeros((), jnp.int32)
-    if unroll:
-        carry = (b, zero, count0)
-        for i in range(k_hvps):
-            carry = body(i, carry)
-    else:
-        carry = jax.lax.fori_loop(0, k_hvps, body, (b, zero, count0))
-    v, acc, count = carry
+    v, acc, count = jax.lax.fori_loop(
+        0, k_hvps, body, (b, zero, jnp.zeros((), jnp.int32)))
     if skip_last:  # the final term joins the sum without a closing HVP
         acc = jax.tree_util.tree_map(jnp.add, acc, v)
     return tree_scale(1.0 / L, acc), count

@@ -15,7 +15,7 @@ import jax
 from jax.flatten_util import ravel_pytree
 
 from repro.kernels.consensus_step.kernel import (
-    DEFAULT_BLOCK_D, consensus_mix_kernel, consensus_step_kernel)
+    consensus_mix_kernel, consensus_step_kernel)
 
 __all__ = ["consensus_mix", "consensus_step", "flatten_agents",
            "unflatten_agents"]
@@ -33,20 +33,17 @@ def unflatten_agents(flat, unravel):
     return jax.vmap(unravel)(flat)
 
 
-@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def consensus_mix(mix: jax.Array, tree, *, block_d: int = DEFAULT_BLOCK_D,
-                  interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def consensus_mix(mix: jax.Array, tree, *, interpret: bool | None = None):
     """Bare combine ``x_i <- sum_j M_ij x_j`` over a pytree (one matmul)."""
     X, unravel = flatten_agents(tree)
-    X_out = consensus_mix_kernel(mix, X, block_d=block_d,
-                                 interpret=interpret)
+    X_out = consensus_mix_kernel(mix, X, interpret=interpret)
     return unflatten_agents(X_out, unravel)
 
 
-@functools.partial(jax.jit, static_argnames=("alpha", "block_d", "interpret"))
+@functools.partial(jax.jit, static_argnames=("alpha", "interpret"))
 def consensus_step(mix: jax.Array, x_tree, u_tree, p_tree, pprev_tree, *,
-                   alpha: float, block_d: int = DEFAULT_BLOCK_D,
-                   interpret: bool = True):
+                   alpha: float, interpret: bool | None = None):
     """Returns (x_tree', u_tree') after one fused eq.(6)+(10) update."""
     X, unravel_x = flatten_agents(x_tree)
     # u gets its own unravel: for mixed-dtype trees, x's unravel would
@@ -56,7 +53,6 @@ def consensus_step(mix: jax.Array, x_tree, u_tree, p_tree, pprev_tree, *,
     PP, _ = flatten_agents(pprev_tree)
 
     X_out, U_out = consensus_step_kernel(mix, X, U, P, PP, alpha=alpha,
-                                         block_d=block_d,
                                          interpret=interpret)
     return (unflatten_agents(X_out, unravel_x),
             unflatten_agents(U_out, unravel_u))

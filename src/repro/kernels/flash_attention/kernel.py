@@ -3,6 +3,9 @@
 Blockwise streaming-softmax attention with explicit VMEM tiling:
 
   grid = (batch, q_heads, num_q_blocks, num_kv_blocks)   kv innermost
+  layout: (batch, heads, seq, head_dim), so every block's last two dims
+          are (BLOCK, head_dim) — the (8, 128) tiling Mosaic requires —
+          and the head is a grid index, never a size-1 block dim
   q block:  (BLOCK_Q, head_dim)  VMEM
   k,v blocks: (BLOCK_K, head_dim) VMEM (indexed by kv head = h // group)
   scratch: running (acc, m, l) in VMEM, persisted across the kv grid dim.
@@ -25,7 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
+from repro.kernels import resolve_interpret
+
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -64,9 +68,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(visible)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -94,12 +98,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     def _finalize():
         l = l_ref[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
 
 
 def flash_attention_kernel(
-    q: jax.Array,  # (batch, q_len, num_heads, head_dim)
-    k: jax.Array,  # (batch, kv_len, num_kv_heads, head_dim)
+    q: jax.Array,  # (batch, num_heads, q_len, head_dim)
+    k: jax.Array,  # (batch, num_kv_heads, kv_len, head_dim)
     v: jax.Array,
     *,
     causal: bool = True,
@@ -108,13 +112,12 @@ def flash_attention_kernel(
     q_offset: int = 0,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """pallas_call wrapper.  Sequence lengths must be block multiples
-    (ops.py pads).  ``interpret=True`` executes on CPU for validation;
-    on TPU pass ``interpret=False``."""
-    b, sq, nh, hd = q.shape
-    _, skv, nkv, _ = k.shape
+    """pallas_call wrapper on head-major (b, h, s, d) tensors.  Sequence
+    lengths must be block multiples (ops.py transposes and pads)."""
+    b, nh, sq, hd = q.shape
+    _, nkv, skv, _ = k.shape
     assert sq % block_q == 0 and skv % block_k == 0, (sq, skv)
     group = nh // nkv
     scale = 1.0 / (hd ** 0.5)
@@ -130,24 +133,24 @@ def flash_attention_kernel(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, ki, hi // group, 0)),
+            pl.BlockSpec((1, 1, block_q, hd),
+                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, nh, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, hd),
+                               lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, nh, sq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, hd), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

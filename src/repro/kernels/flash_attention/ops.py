@@ -43,17 +43,21 @@ def flash_attention(
     q_offset: int = 0,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
+    """Attention on (batch, seq, heads, head_dim) tensors, as the models
+    hold them; the kernel runs head-major, so q/k/v are transposed in and
+    the output back out."""
     b, sq, nh, hd = q.shape
     skv = k.shape[1]
     block_q = min(block_q, max(8, sq))
     block_k = min(block_k, max(8, skv))
     sq_p = -(-sq // block_q) * block_q
     skv_p = -(-skv // block_k) * block_k
-    qp = _pad_to(q, sq_p, 1)
-    kp = _pad_to(k, skv_p, 1)
-    vp = _pad_to(v, skv_p, 1)
+    head_major = lambda t: jnp.swapaxes(t, 1, 2)
+    qp = _pad_to(head_major(q), sq_p, 2)
+    kp = _pad_to(head_major(k), skv_p, 2)
+    vp = _pad_to(head_major(v), skv_p, 2)
     # Padded kv columns must never be attended to.  Causal masking already
     # hides them from real rows when q and kv are co-indexed; for the
     # decode path (q_offset > 0) the window/causal mask built from global
@@ -63,4 +67,4 @@ def flash_attention(
         qp, kp, vp, causal=causal, window=window,
         logit_softcap=logit_softcap, q_offset=q_offset,
         block_q=block_q, block_k=block_k, interpret=interpret)
-    return out[:, :sq]
+    return jnp.swapaxes(out[:, :, :sq], 1, 2)

@@ -22,8 +22,11 @@ from repro.kernels.rwkv6.kernel import DEFAULT_CHUNK, wkv6_kernel
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
          u: jax.Array, state: jax.Array | None = None,
-         chunk: int = DEFAULT_CHUNK, interpret: bool = True
+         chunk: int = DEFAULT_CHUNK, interpret: bool | None = None
          ) -> tuple[jax.Array, jax.Array]:
+    """WKV6 on (batch, seq, heads, N) tensors, as the model holds them;
+    the kernel runs head-major, so inputs are transposed in and the
+    output back out."""
     b, s, h, n = r.shape
     chunk = min(chunk, s) if s % min(chunk, s) == 0 else 1 if s == 1 else chunk
     pad = (-s) % chunk
@@ -36,9 +39,11 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     else:
         r_, k_, v_, w_ = r, k, v, w
 
-    out, s_final = wkv6_kernel(r_, k_, v_, w_, u, chunk=chunk,
+    head_major = lambda t: jnp.swapaxes(t, 1, 2)
+    out, s_final = wkv6_kernel(head_major(r_), head_major(k_), head_major(v_),
+                               head_major(w_), u, chunk=chunk,
                                interpret=interpret)
-    out = out[:, :s]
+    out = jnp.swapaxes(out, 1, 2)[:, :s]
 
     if state is not None:
         logw = jnp.log(jnp.maximum(w.astype(jnp.float32), 1e-38))

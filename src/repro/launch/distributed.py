@@ -13,9 +13,8 @@ closes that gap:
   spans every process after initialize), agents on the ``data`` axis.
 * ``run_section6`` — the paper's Section-6 synthetic instance stepped by
   the registry INTERACT solver whose raw step body is wrapped in a
-  *full-manual* shard_map over the mesh (the old-JAX partitioner cannot
-  lower collectives inside partially-manual bodies — sharding/compat),
-  with the eq.-11 stationarity metric recorded host-side and a
+  *full-manual* shard_map over the mesh, with the eq.-11 stationarity
+  metric recorded host-side and a
   ``CommsLedger`` measuring the bytes the compiled program actually
   ships (docs/DISTRIBUTED.md).
 
@@ -36,7 +35,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.mesh import make_production_mesh
-from repro.sharding.compat import set_mesh, shard_map
 
 __all__ = [
     "DistributedConfig",
@@ -115,14 +113,16 @@ def agent_mesh(num_agents: int):
     device count must be a multiple of ``num_agents`` (the surplus
     becomes the model axis).  Raises an actionable error otherwise.
     """
-    n = len(jax.devices())
+    devices = jax.devices()
+    n = len(devices)
     m = int(num_agents)
     if m < 1 or n < m or n % m:
+        hint = f"pick m from the divisors of {n}"
+        if devices[0].platform == "cpu":
+            hint += (", or relaunch with --devices-per-process so processes "
+                     "x devices is a multiple of m (scripts/launch_local.py)")
         raise ValueError(
-            f"num_agents={m} does not divide the {n} mesh devices — pick "
-            f"m from the divisors of {n}, or relaunch with "
-            f"--devices-per-process so processes x devices is a multiple "
-            f"of m (scripts/launch_local.py)")
+            f"num_agents={m} does not divide the {n} mesh devices — {hint}")
     model = n // m
     shape = (m,) if model == 1 else (m, model)
     return make_production_mesh(shape=shape)
@@ -247,9 +247,9 @@ def run_section6(*, num_agents: int = 8, num_steps: int = 30,
     dspec = _spec_tree(host_data, m)
     manual = set(mesh.axis_names)
     raw = solver._raw_step
-    smap_step = shard_map(raw, mesh=mesh, in_specs=(sspec, dspec),
-                          out_specs=sspec, axis_names=manual,
-                          check_vma=False)
+    smap_step = jax.shard_map(raw, mesh=mesh, in_specs=(sspec, dspec),
+                              out_specs=sspec, axis_names=manual,
+                              check_vma=False)
 
     def chunk(s, d, length):
         def body(c, _):
@@ -274,7 +274,7 @@ def run_section6(*, num_agents: int = 8, num_steps: int = 30,
         lengths.append(num_steps % step_chunk)
 
     trace = []
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for length in lengths:
             trace.append(metric(gstate))
             gstate = jchunk(gstate, gdata, length)
@@ -283,7 +283,7 @@ def run_section6(*, num_agents: int = 8, num_steps: int = 30,
 
         engine = solver._engine
         xspec = _spec_tree(host_state.x, m)
-        mix_fn = jax.jit(shard_map(
+        mix_fn = jax.jit(jax.shard_map(
             lambda t: engine.mix(t), mesh=mesh, in_specs=(xspec,),
             out_specs=xspec, axis_names=manual, check_vma=False))
         ledger.observe_latency(

@@ -40,7 +40,6 @@ from repro.launch import input_specs as specs_mod
 from repro.launch.mesh import agent_axes, make_production_mesh
 from repro.launch.serving import make_prefill_step, make_serve_step
 from repro.roofline.analysis import normalize_cost_analysis
-from repro.sharding.compat import set_mesh
 from repro.models.base import ArchConfig
 from repro.sharding.partition import (
     cache_specs, leaf_spec, tree_shardings, tree_specs)
@@ -158,7 +157,7 @@ def lower_train(cfg: ArchConfig, mesh, opt: bool = False,
                         "grad_norm": NamedSharding(mesh, P())}),
         donate_argnums=(0,),
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jitted.lower(*args)
 
 
@@ -181,7 +180,7 @@ def lower_prefill(cfg: ArchConfig, mesh, opt: bool = False):
         in_sh.append(NamedSharding(mesh, P(dent)))
     jitted = jax.jit(fn, in_shardings=tuple(in_sh),
                      out_shardings=NamedSharding(mesh, P(dent, "model")))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jitted.lower(*args)
 
 
@@ -210,7 +209,7 @@ def lower_decode(cfg: ArchConfig, mesh, shape: str, opt: bool = False):
         out_shardings=(NamedSharding(mesh, tok_spec), c_shardings),
         donate_argnums=(2,),
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jitted.lower(params_sh, inputs["token"], inputs["cache"],
                             inputs["position"])
 

@@ -13,6 +13,11 @@ smoke runs build e.g. a ``(8,)`` mesh over 2 processes x 4 forced host
 devices.  Axis names default by rank: ``("data",)``, ``("data",
 "model")``, ``("pod", "data", "model")``.
 
+Every mesh the program builds goes through ``make_mesh``, which marks
+all axes ``Auto``: ``jax.make_mesh`` defaults to ``Explicit`` axes, under
+which an einsum whose contracting dim is sharded over ``model`` is a type
+error instead of an all-reduce the partitioner inserts.
+
 Defined as functions (never module-level constants) so importing this
 module does not touch jax device state.
 """
@@ -21,11 +26,22 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "agent_axes", "agent_count", "model_axis"]
+__all__ = ["make_mesh", "make_production_mesh", "agent_axes", "agent_count",
+           "model_axis"]
 
 _DEFAULT_AXES = {1: ("data",), 2: ("data", "model"),
                  3: ("pod", "data", "model")}
+
+
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str], *,
+              devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (partitioner-driven)."""
+    shape = tuple(shape)
+    return jax.make_mesh(shape, tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -66,12 +82,14 @@ def make_production_mesh(*, multi_pod: bool = False,
     need = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) < need:
+        hint = "pass a smaller shape="
+        if devices[0].platform == "cpu":
+            hint = ("set XLA_FLAGS=--xla_force_host_platform_device_count="
+                    f"{need} before any jax import, or " + hint)
         raise RuntimeError(
-            f"mesh {shape} needs {need} devices, found {len(devices)} — the "
-            "dry-run launcher must set XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count={need} before any jax import (or pass a smaller "
-            "shape=)")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+            f"mesh {shape} needs {need} devices, found {len(devices)} "
+            f"{devices[0].platform} devices — {hint}")
+    return make_mesh(shape, axes, devices=devices[:need])
 
 
 def agent_axes(mesh) -> tuple[str, ...]:

@@ -2,7 +2,8 @@
 
 Runs real INTERACT iterations (not a dry-run) on whatever devices exist —
 the same code path scales from 1 CPU to the production mesh.  For CPU use,
-pick a reduced config (``--reduced``).
+pick a reduced config (``--reduced``) and give the host as many devices as
+agents (``XLA_FLAGS=--xla_force_host_platform_device_count=4``).
 
 Example (the deliverable-scale run: ~100M-param model, few hundred steps):
 
@@ -15,6 +16,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -24,28 +26,46 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.checkpoint import latest_step, restore_step, save_step
 from repro.configs import ARCH_IDS, get_config
 from repro.data.synthetic import TokenTaskStream
-from repro.launch.mesh import agent_axes, make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import agent_axes, make_mesh, make_production_mesh
 from repro.sharding.partition import tree_shardings
 from repro.train.bilevel_lm import BilevelHyper
-from repro.sharding.compat import set_mesh
 from repro.train.step import (
-    InteractConfig, init_train_state, make_train_step, train_state_specs)
+    InteractConfig, TrainState, init_train_state, make_train_step,
+    train_state_specs)
 
 
 def make_host_mesh(num_agents: int):
-    """A mesh over however many real devices exist: agents on 'data'."""
+    """A ``(num_agents, model)`` mesh over the devices that exist.
+
+    One agent per mesh row; the devices left over per agent form the
+    ``model`` axis.  Refuses an agent count the devices cannot hold
+    rather than training fewer agents than asked for.
+    """
     devs = jax.devices()
     n = len(devs)
-    model = max(1, n // num_agents)
-    data = min(num_agents, n)
-    if data * model > n:
-        model = 1
-    import numpy as np
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devs[:data * model])
+    m = int(num_agents)
+    if m < 1 or m > n:
+        hint = ""
+        if devs[0].platform == "cpu":
+            hint = (" — on CPU set XLA_FLAGS=--xla_force_host_platform_"
+                    f"device_count={max(m, 1)} before any jax import")
+        raise ValueError(
+            f"--agents {m} needs one {devs[0].platform} device per agent, "
+            f"found {n}{hint}")
+    model = n // m
+    return make_mesh((m, model), ("data", "model"), devices=devs[:m * model])
 
 
-def main() -> None:
+@dataclasses.dataclass
+class TrainRun:
+    """What ``main`` returns: the final state and the logged steps."""
+
+    state: TrainState
+    log: list[dict]
+
+
+def main(argv: Sequence[str] | None = None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS), default="smollm-360m")
     ap.add_argument("--reduced", action="store_true",
@@ -61,7 +81,8 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--production-mesh", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -106,7 +127,8 @@ def main() -> None:
     step_fn = make_train_step(cfg, mesh, icfg)
     tok_shard = NamedSharding(mesh, P(aent))
 
-    with set_mesh(mesh):
+    log = []
+    with jax.set_mesh(mesh):
         jstep = jax.jit(step_fn, donate_argnums=(0,))
         t0 = time.time()
         for t in range(start, args.steps):
@@ -118,6 +140,8 @@ def main() -> None:
                 ce = float(metrics["outer_ce"])
                 gn = float(metrics["grad_norm"])
                 dt = (time.time() - t0) / args.log_every
+                log.append({"step": t + 1, "outer_ce": ce, "grad_norm": gn,
+                            "s_per_step": dt})
                 print(f"step {t + 1:5d}  outer_ce {ce:.4f}  "
                       f"tracked_grad_norm {gn:.3e}  {dt:.2f}s/step")
                 t0 = time.time()
@@ -126,6 +150,7 @@ def main() -> None:
                 print(f"checkpointed step {t + 1}")
 
     print("done.")
+    return TrainRun(state=state, log=log)
 
 
 if __name__ == "__main__":

@@ -202,19 +202,13 @@ def features(cfg: ArchConfig, params: dict, tokens: jax.Array,
              prefix_embed: jax.Array | None = None,
              impl: str = "reference", remat: bool = True,
              moe_impl: str = "capacity",
-             act_spec=None, scan_layers: bool = True
-             ) -> tuple[jax.Array, jax.Array]:
+             act_spec=None) -> tuple[jax.Array, jax.Array]:
     """Backbone features: (batch, seq[, +prefix], d_model), plus MoE aux loss.
 
     ``act_spec``: optional PartitionSpec applied to the residual stream at
     every period boundary (sequence parallelism — perf iteration P4): the
     tensors *saved for backward* live sequence-sharded over the model
     axis; XLA gathers heads/kv only where attention needs them.
-
-    ``scan_layers=False`` unrolls the period loop as Python — required
-    inside partially-manual shard_map bodies on old-JAX stacks, whose
-    partitioner cannot shard a while-loop over manual subgroups (see
-    repro/sharding/compat.PARTIAL_AUTO_COLLECTIVES_SAFE).
     """
     x = _embed_inputs(cfg, params, tokens, prefix_embed)
     positions = jnp.arange(x.shape[1], dtype=jnp.int32)
@@ -235,16 +229,7 @@ def features(cfg: ArchConfig, params: dict, tokens: jax.Array,
 
     body = jax.checkpoint(period_body) if remat else period_body
     carry0 = (constrain(x), jnp.zeros((), jnp.float32))
-    if scan_layers:
-        (x, aux), _ = jax.lax.scan(body, carry0, params["layers"])
-    else:
-        carry = carry0
-        n_periods = jax.tree_util.tree_leaves(
-            params["layers"])[0].shape[0]
-        for i in range(n_periods):
-            carry, _ = body(carry, jax.tree_util.tree_map(
-                lambda l: l[i], params["layers"]))
-        x, aux = carry
+    (x, aux), _ = jax.lax.scan(body, carry0, params["layers"])
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     return x, aux
 

@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.sharding.compat import axis_size
 
 __all__ = [
     "PermuteSchedule", "PermuteWeights", "permute_schedule",
@@ -217,10 +216,8 @@ def _psum_mix(x, name, m, schedule, i, compress, dp_sigma, dp_key,
     """All-reduce realisation: agent j contributes M[:, j] (x) sent_j and
     everyone slices its own row of the psum.
 
-    Used where the partitioner cannot lower ppermute under a partially
-    manual shard_map (old-JAX stacks, see compat.PARTIAL_AUTO_COLLECTIVES
-    _SAFE); costs one m-times-payload all-reduce instead of per-edge
-    exchanges, but preserves the exact mixing semantics — including that
+    Costs one m-times-payload all-reduce instead of per-edge exchanges,
+    but preserves the exact mixing semantics — including that
     the agent's *own* term mixes the clean local iterate while neighbours
     see the compressed / noised payload.  ``payload`` overrides the
     outgoing value (pre-compressed by the engine's error-feedback layer);
@@ -276,7 +273,7 @@ def permute_mix_leaf(x: jax.Array, agent_axes: Sequence[str],
     shared offset schedule (time-varying topologies, docs/TOPOLOGY.md).
     """
     name = _axis_name(agent_axes)
-    m = axis_size(name)
+    m = jax.lax.axis_size(name)
     if m != schedule.num_agents:
         raise ValueError(
             f"schedule built for m={schedule.num_agents} but the agent "
@@ -316,7 +313,7 @@ def ring_mix_leaf(x: jax.Array, agent_axes: Sequence[str],
     """Ring special case: the schedule of ``ring_mixing(m, self_weight)``."""
     from repro.core.consensus import ring_mixing  # lazy: avoids core cycle
     name = _axis_name(agent_axes)
-    m = axis_size(name)
+    m = jax.lax.axis_size(name)
     schedule = permute_schedule(ring_mixing(m, self_weight=self_weight))
     return permute_mix_leaf(x, agent_axes, schedule, compress=compress,
                             dp_sigma=dp_sigma, dp_key=dp_key,

@@ -51,9 +51,6 @@ class BilevelHyper:
     seq_shard: bool = False   # P4: sequence-shard the residual stream
     batch_shard: bool = False  # P6: batch-shard residuals over 'data'
     microbatch: int = 1        # P8: gradient-accumulation microbatches
-    unroll_scans: bool = False  # old-JAX partial-auto shard_map compat:
-    #   unroll layer scan / CE scan / Neumann loop (the SPMD partitioner
-    #   there cannot shard while-loops over manual subgroups)
 
 
 def ridge(y: jax.Array, mu: float) -> jax.Array:
@@ -61,8 +58,7 @@ def ridge(y: jax.Array, mu: float) -> jax.Array:
 
 
 def chunked_ce(cfg: ArchConfig, head: jax.Array, feats: jax.Array,
-               labels: jax.Array, chunk: int,
-               unroll: bool = False) -> jax.Array:
+               labels: jax.Array, chunk: int) -> jax.Array:
     """Next-token CE with the head applied chunk-by-chunk over tokens.
 
     feats: (b, s, d) backbone outputs; labels: (b, s) token ids (the
@@ -93,13 +89,7 @@ def chunked_ce(cfg: ArchConfig, head: jax.Array, feats: jax.Array,
         gold = jnp.take_along_axis(logits, lc[:, None], axis=-1)[:, 0]
         return acc + jnp.sum((logz - gold) * vc), None
 
-    if unroll:
-        total = jnp.zeros((), jnp.float32)
-        for c in range(ft.shape[0]):
-            total, _ = body(total, (ft[c], lt[c], vt[c]))
-    else:
-        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
-                                (ft, lt, vt))
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (ft, lt, vt))
     return total / jnp.float32(n)
 
 
@@ -112,30 +102,26 @@ def _backbone(cfg: ArchConfig, x, tokens, prefix, hyper: BilevelHyper):
         act_spec = P(None, "model", None)
     return M.features(cfg, x, tokens, prefix_embed=prefix,
                       impl=hyper.attn_impl, remat=hyper.remat,
-                      act_spec=act_spec,
-                      scan_layers=not hyper.unroll_scans)
+                      act_spec=act_spec)
 
 
 def inner_loss(cfg: ArchConfig, hyper: BilevelHyper, x, y, tokens,
                prefix=None) -> jax.Array:
     feats, _aux = _backbone(cfg, x, tokens, prefix, hyper)
-    return (chunked_ce(cfg, y, feats, tokens, hyper.ce_chunk,
-                       unroll=hyper.unroll_scans)
+    return (chunked_ce(cfg, y, feats, tokens, hyper.ce_chunk)
             + ridge(y, hyper.mu_g))
 
 
 def outer_loss(cfg: ArchConfig, hyper: BilevelHyper, x, y, tokens,
                prefix=None) -> jax.Array:
     feats, aux = _backbone(cfg, x, tokens, prefix, hyper)
-    ce = chunked_ce(cfg, y, feats, tokens, hyper.ce_chunk,
-                    unroll=hyper.unroll_scans)
+    ce = chunked_ce(cfg, y, feats, tokens, hyper.ce_chunk)
     return ce + cfg.router_aux_weight * aux
 
 
 def _head_loss_on_feats(cfg: ArchConfig, hyper: BilevelHyper, y, feats,
                         labels) -> jax.Array:
-    return (chunked_ce(cfg, y, feats, labels, hyper.ce_chunk,
-                       unroll=hyper.unroll_scans)
+    return (chunked_ce(cfg, y, feats, labels, hyper.ce_chunk)
             + ridge(y, hyper.mu_g))
 
 
@@ -153,8 +139,7 @@ def _neumann_head(cfg, hyper: BilevelHyper, y, feats, labels, b):
         lambda yy: _head_loss_on_feats(cfg, hyper, yy, feats, labels))
     _, hvp_lin = jax.linearize(grad_fn, y)
     z, _count = neumann_truncated_apply(
-        hvp_lin, b, hyper.neumann_k, hyper.lipschitz_g,
-        unroll=hyper.unroll_scans, skip_last=True)
+        hvp_lin, b, hyper.neumann_k, hyper.lipschitz_g, skip_last=True)
     return z
 
 

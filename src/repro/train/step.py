@@ -37,7 +37,6 @@ from repro.core.consensus import MixingSpec
 from repro.launch.mesh import agent_axes, agent_count
 from repro.models import model as M
 from repro.models.base import ArchConfig
-from repro.sharding.compat import PARTIAL_AUTO_COLLECTIVES_SAFE, shard_map
 from repro.sharding.partition import (
     leaf_spec, stacked_tree_specs, tree_shardings)
 from repro.train.bilevel_lm import BilevelHyper, local_grads
@@ -147,37 +146,17 @@ class InteractConfig:
             return cfg
         return cls.from_solver_config(cfg, hyper=hyper)
 
-    def compat_hyper(self, a_axes, mesh) -> BilevelHyper:
-        """The hyper config adjusted for the shard_map body: on old-JAX
-        stacks a partially-auto body cannot contain while-loops over
-        manual subgroups, so every scan in the backbone unrolls."""
-        if (set(mesh.axis_names) - set(a_axes)
-                and not PARTIAL_AUTO_COLLECTIVES_SAFE):
-            return dataclasses.replace(self.hyper, unroll_scans=True)
-        return self.hyper
-
-    def consensus_engine(self, m: int, a_axes, mesh=None):
-        """Build the distributed consensus engine for this config.
-
-        When the mesh carries auto (non-agent) axes and the JAX stack
-        cannot lower ppermute under a partially-manual body, the engine
-        falls back to the psum realisation of the same mixing matrix
-        (see sharding/compat.PARTIAL_AUTO_COLLECTIVES_SAFE).
-        """
+    def consensus_engine(self, m: int, a_axes):
+        """Build the distributed consensus engine for this config."""
         if self.consensus_backend != "ppermute":
             raise ValueError(
                 f"backend {self.consensus_backend!r} cannot run inside "
                 "shard_map; the distributed runtime requires 'ppermute' "
                 "(dense/pallas serve the single-host simulator)")
-        impl = "ppermute"
-        if (mesh is not None
-                and set(mesh.axis_names) - set(a_axes)
-                and not PARTIAL_AUTO_COLLECTIVES_SAFE):
-            impl = "psum"
         return make_engine("ppermute", self.mixing_spec(m),
                            agent_axes=tuple(a_axes),
                            compress=self.consensus_compress,
-                           dp_sigma=self.dp_sigma, impl=impl)
+                           dp_sigma=self.dp_sigma)
 
 
 def _zeros_like_tree(tree):
@@ -271,13 +250,12 @@ def make_train_step(cfg: ArchConfig, mesh, icfg: InteractConfig,
     for ax in a_axes:
         m *= mesh.shape[ax]
     aentry = _agent_entry(a_axes)
-    hyper = icfg.compat_hyper(a_axes, mesh)
-    engine = icfg.consensus_engine(m, a_axes, mesh=mesh)
+    hyper = icfg.hyper
+    engine = icfg.consensus_engine(m, a_axes)
 
     def per_agent(state: TrainState, tokens, ids, prefix):
         # Leaves arrive with leading agent dim of local size 1; ``ids``
-        # threads each agent's ring position in as data (axis_index does
-        # not lower under partially-auto bodies on old JAX).
+        # threads each agent's ring position in as data.
         sq = lambda t: jax.tree_util.tree_map(lambda l: l[0], t)
         un = lambda t: jax.tree_util.tree_map(lambda l: l[None], t)
         agent_idx = ids[0]
@@ -330,13 +308,13 @@ def make_train_step(cfg: ArchConfig, mesh, icfg: InteractConfig,
         out_specs = (specs_state, {"outer_ce": P(), "grad_norm": P()})
         ids = jnp.arange(m, dtype=jnp.int32)
         if prefix is None:
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda s, tk, ii: per_agent(s, tk, ii, None), mesh=mesh,
                 in_specs=(specs_state, P(aentry), P(aentry)),
                 out_specs=out_specs, axis_names=set(a_axes),
                 check_vma=False)
             return fn(state, tokens, ids)
-        fn = shard_map(
+        fn = jax.shard_map(
             per_agent, mesh=mesh,
             in_specs=(specs_state, P(aentry), P(aentry), P(aentry)),
             out_specs=out_specs, axis_names=set(a_axes),
@@ -351,7 +329,7 @@ def make_eval_step(cfg: ArchConfig, mesh, icfg: InteractConfig):
     icfg = InteractConfig.coerce(icfg)
     a_axes = agent_axes(mesh)
     aentry = _agent_entry(a_axes)
-    hyper = icfg.compat_hyper(a_axes, mesh)
+    hyper = icfg.hyper
 
     def per_agent(state: TrainState, tokens):
         from repro.train.bilevel_lm import outer_loss
@@ -362,10 +340,10 @@ def make_eval_step(cfg: ArchConfig, mesh, icfg: InteractConfig):
     def step(state, tokens):
         specs_state = jax.tree_util.tree_map(lambda _: P(aentry), state)
         specs_state = specs_state._replace(t=P())
-        return shard_map(per_agent, mesh=mesh,
-                         in_specs=(specs_state, P(aentry)),
-                         out_specs=P(),
-                         axis_names=set(a_axes),
-                         check_vma=False)(state, tokens)
+        return jax.shard_map(per_agent, mesh=mesh,
+                             in_specs=(specs_state, P(aentry)),
+                             out_specs=P(),
+                             axis_names=set(a_axes),
+                             check_vma=False)(state, tokens)
 
     return step

@@ -34,7 +34,6 @@ from jax.sharding import PartitionSpec as P
 from repro.consensus import consensus_descent_and_track
 from repro.launch.mesh import agent_axes
 from repro.models.base import ArchConfig
-from repro.sharding.compat import shard_map
 from repro.train.bilevel_lm import local_grads
 from repro.train.step import InteractConfig, TrainState, _agent_entry
 
@@ -93,11 +92,11 @@ def make_svr_train_step(cfg: ArchConfig, mesh, icfg: InteractConfig,
         q = icfg.q
     a_axes = ("pod",) if agent_mode == "pods" else agent_axes(mesh)
     aentry = _agent_entry(a_axes)
-    hyper = icfg.compat_hyper(a_axes, mesh)
+    hyper = icfg.hyper
     m = 1
     for ax in a_axes:
         m *= mesh.shape[ax]
-    engine = icfg.consensus_engine(m, a_axes, mesh=mesh)
+    engine = icfg.consensus_engine(m, a_axes)
 
     def per_agent(state: SvrTrainState, tokens, ids):
         sq = lambda t: jax.tree_util.tree_map(lambda l: l[0], t)
@@ -142,10 +141,10 @@ def make_svr_train_step(cfg: ArchConfig, mesh, icfg: InteractConfig,
         specs_state = specs_state._replace(t=P())
         out_specs = (specs_state, {"outer_ce": P(), "refresh": P()})
         ids = jnp.arange(m, dtype=jnp.int32)
-        fn = shard_map(per_agent, mesh=mesh,
-                       in_specs=(specs_state, P(aentry), P(aentry)),
-                       out_specs=out_specs,
-                       axis_names=set(a_axes), check_vma=False)
+        fn = jax.shard_map(per_agent, mesh=mesh,
+                           in_specs=(specs_state, P(aentry), P(aentry)),
+                           out_specs=out_specs,
+                           axis_names=set(a_axes), check_vma=False)
         return fn(state, tokens, ids)
 
     return step

@@ -1,12 +1,11 @@
 """Shared pytest fixtures.
 
-``jax.clear_caches()`` runs after every test module: jaxlib 0.4.37's CPU
-``backend_compile`` segfaults once a few hundred compiled executables
-have accumulated across a full-suite run (each module passes standalone;
-the crash moves with the collection order, landing on whichever
-compile-heavy test runs ~280 tests in).  Dropping the compilation caches
-at module boundaries bounds live compiler state at the footprint of one
-module, at the cost of cross-module recompiles.
+``jax.clear_caches()`` runs after every test module.  A test worker runs
+many modules in one process, and every jitted program each of them
+compiles stays alive in JAX's caches until the process exits; dropping
+the caches at module boundaries bounds the worker's live executables and
+host memory at the footprint of one module, at the cost of cross-module
+recompiles.
 """
 import jax
 import pytest

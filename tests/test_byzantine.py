@@ -256,9 +256,9 @@ def test_dense_vs_ppermute_weighted_under_attack():
         from repro.byzantine import ByzantineConfig
         from repro.consensus import DenseEngine, PermuteEngine
         from repro.core import ring_mixing
-        from repro.sharding.compat import shard_map, set_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         spec = ring_mixing(m, self_weight=1/3)
         bcfg = ByzantineConfig(kind="gaussian", num_byzantine=2,
@@ -267,10 +267,10 @@ def test_dense_vs_ppermute_weighted_under_attack():
                 "b": jax.random.normal(jax.random.PRNGKey(1), (m, 131))}
         dense = DenseEngine(spec, byzantine=bcfg)
         eng = PermuteEngine(spec, agent_axes=("data",), byzantine=bcfg)
-        fn = shard_map(lambda t: eng.mix_ef(t, None, 0, stream="x")[0],
-                       mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                       axis_names={"data"}, check_vma=False)
-        with set_mesh(mesh):
+        fn = jax.shard_map(lambda t: eng.mix_ef(t, None, 0, stream="x")[0],
+                           mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                           axis_names={"data"}, check_vma=False)
+        with jax.set_mesh(mesh):
             got = jax.jit(fn)(tree)
         want, _ = dense.mix_ef(tree, None, 0, stream="x")
         for a, b in zip(jax.tree_util.tree_leaves(got),

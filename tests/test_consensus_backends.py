@@ -146,9 +146,9 @@ def test_ppermute_backend_matches_dense_all_topologies():
         from repro.consensus import DenseEngine, PermuteEngine
         from repro.core import (erdos_renyi_adjacency, laplacian_mixing,
                                 ring_mixing, torus_mixing)
-        from repro.sharding.compat import shard_map, set_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         specs = {
             "ring": ring_mixing(m, self_weight=1/3),
@@ -164,23 +164,23 @@ def test_ppermute_backend_matches_dense_all_topologies():
         for name, spec in specs.items():
             eng = PermuteEngine(spec, agent_axes=("data",))
             dense = DenseEngine(spec)
-            fn = shard_map(lambda t: eng.mix(t), mesh=mesh,
-                           in_specs=P("data"), out_specs=P("data"),
-                           axis_names={"data"}, check_vma=False)
-            with set_mesh(mesh):
+            fn = jax.shard_map(lambda t: eng.mix(t), mesh=mesh,
+                               in_specs=P("data"), out_specs=P("data"),
+                               axis_names={"data"}, check_vma=False)
+            with jax.set_mesh(mesh):
                 got = jax.jit(fn)(tree)
             want = dense.mix(tree)
             for a, b in zip(jax.tree_util.tree_leaves(got),
                             jax.tree_util.tree_leaves(want)):
                 np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                            atol=1e-5)
-            fused = shard_map(
+            fused = jax.shard_map(
                 lambda x_, u_, p_, pp_: eng.step1_step3(x_, u_, p_, pp_,
                                                         0.3),
                 mesh=mesh, in_specs=(P("data"),) * 4,
                 out_specs=(P("data"), P("data")), axis_names={"data"},
                 check_vma=False)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 xg, ug = jax.jit(fused)(tree, u, p, pp)
             xd, ud = dense.step1_step3(tree, u, p, pp, 0.3)
             for a, b in zip(jax.tree_util.tree_leaves(xg),
@@ -209,9 +209,9 @@ def test_allgather_backend_matches_dense_all_topologies():
         from repro.consensus import AllGatherEngine, DenseEngine
         from repro.core import (erdos_renyi_adjacency, laplacian_mixing,
                                 ring_mixing, torus_mixing)
-        from repro.sharding.compat import shard_map, set_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         specs = {
             "ring": ring_mixing(m, self_weight=1/3),
@@ -227,23 +227,23 @@ def test_allgather_backend_matches_dense_all_topologies():
         for name, spec in specs.items():
             eng = AllGatherEngine(spec, agent_axes=("data",))
             dense = DenseEngine(spec)
-            fn = shard_map(lambda t: eng.mix(t), mesh=mesh,
-                           in_specs=P("data"), out_specs=P("data"),
-                           axis_names={"data"}, check_vma=False)
-            with set_mesh(mesh):
+            fn = jax.shard_map(lambda t: eng.mix(t), mesh=mesh,
+                               in_specs=P("data"), out_specs=P("data"),
+                               axis_names={"data"}, check_vma=False)
+            with jax.set_mesh(mesh):
                 got = jax.jit(fn)(tree)
             want = dense.mix(tree)
             for a, b in zip(jax.tree_util.tree_leaves(got),
                             jax.tree_util.tree_leaves(want)):
                 np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                            atol=1e-5)
-            fused = shard_map(
+            fused = jax.shard_map(
                 lambda x_, u_, p_, pp_: eng.step1_step3(x_, u_, p_, pp_,
                                                         0.3),
                 mesh=mesh, in_specs=(P("data"),) * 4,
                 out_specs=(P("data"), P("data")), axis_names={"data"},
                 check_vma=False)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 xg, ug = jax.jit(fused)(tree, u, p, pp)
             xd, ud = dense.step1_step3(tree, u, p, pp, 0.3)
             for a, b in zip(jax.tree_util.tree_leaves(xg),
@@ -262,10 +262,10 @@ def test_allgather_backend_matches_dense_all_topologies():
         byz = ByzantineConfig(combine="trimmed-mean")
         spec = specs["erdos-renyi"]
         engr = AllGatherEngine(spec, agent_axes=("data",), byzantine=byz)
-        fn = shard_map(lambda t: engr._combine(t), mesh=mesh,
-                       in_specs=P("data"), out_specs=P("data"),
-                       axis_names={"data"}, check_vma=False)
-        with set_mesh(mesh):
+        fn = jax.shard_map(lambda t: engr._combine(t), mesh=mesh,
+                           in_specs=P("data"), out_specs=P("data"),
+                           axis_names={"data"}, check_vma=False)
+        with jax.set_mesh(mesh):
             got = jax.jit(fn)(tree)
         want = DenseEngine(spec, byzantine=byz)._combine(tree)
         for a, b in zip(jax.tree_util.tree_leaves(got),
@@ -288,9 +288,9 @@ def test_allgather_compressed_mix_ef_matches_dense_bitwise():
         from repro.consensus import (AllGatherEngine, CompressionConfig,
                                      DenseEngine)
         from repro.core import erdos_renyi_adjacency, laplacian_mixing
-        from repro.sharding.compat import shard_map, set_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         spec = laplacian_mixing(erdos_renyi_adjacency(m, 0.5, seed=11))
         tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (m, 37, 5)),
@@ -303,11 +303,11 @@ def test_allgather_compressed_mix_ef_matches_dense_bitwise():
 
         md, efd = DenseEngine(spec, compression=comp).mix_ef(tree, ef, t0)
         eng = AllGatherEngine(spec, agent_axes=("data",), compression=comp)
-        fn = shard_map(lambda t, r: eng.mix_ef(t, r, t0), mesh=mesh,
-                       in_specs=(P("data"), P("data")),
-                       out_specs=(P("data"), P("data")),
-                       axis_names={"data"}, check_vma=False)
-        with set_mesh(mesh):
+        fn = jax.shard_map(lambda t, r: eng.mix_ef(t, r, t0), mesh=mesh,
+                           in_specs=(P("data"), P("data")),
+                           out_specs=(P("data"), P("data")),
+                           axis_names={"data"}, check_vma=False)
+        with jax.set_mesh(mesh):
             mg, efg = jax.jit(fn)(tree, ef)
         for a, b in zip(jax.tree_util.tree_leaves(mg),
                         jax.tree_util.tree_leaves(md)):
@@ -344,19 +344,19 @@ def test_dp_noise_independent_across_leaves():
         from jax.sharding import PartitionSpec as P
         from repro.core import ring_mixing
         from repro.sharding.collectives import ring_mix_tree
-        from repro.sharding.compat import shard_map, set_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         spec = ring_mixing(m, self_weight=1/3)
         leaf = jax.random.normal(jax.random.PRNGKey(0), (m, 32))
         tree = {"a": leaf, "b": leaf}     # identical same-shaped leaves
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda t: ring_mix_tree(t, ("data",), 1/3, dp_sigma=0.1,
                                     dp_key=jax.random.PRNGKey(3)),
             mesh=mesh, in_specs=P("data"), out_specs=P("data"),
             axis_names={"data"}, check_vma=False)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = jax.jit(fn)(tree)
         # identical inputs + identical noise would give identical outputs;
         # independent per-leaf noise must make them differ
@@ -375,9 +375,9 @@ def test_psum_impl_matches_ppermute_impl():
         from jax.sharding import PartitionSpec as P
         from repro.consensus import PermuteEngine
         from repro.core import erdos_renyi_adjacency, laplacian_mixing
-        from repro.sharding.compat import shard_map, set_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         spec = laplacian_mixing(erdos_renyi_adjacency(m, 0.5, seed=4))
         X = jax.random.normal(jax.random.PRNGKey(0), (m, 64))
@@ -387,12 +387,12 @@ def test_psum_impl_matches_ppermute_impl():
             for impl in ("ppermute", "psum"):
                 eng = PermuteEngine(spec, agent_axes=("data",),
                                     compress=compress, impl=impl)
-                fn = shard_map(
+                fn = jax.shard_map(
                     lambda t, ii: eng.mix(t, agent_index=ii[0]),
                     mesh=mesh, in_specs=(P("data"), P("data")),
                     out_specs=P("data"), axis_names={"data"},
                     check_vma=False)
-                with set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     outs.append(jax.jit(fn)(X, ids))
             np.testing.assert_allclose(np.asarray(outs[0]),
                                        np.asarray(outs[1]), atol=1e-5)
@@ -469,9 +469,9 @@ def test_int8_compression_dense_and_ppermute_tolerance_contract():
         from repro.consensus import (CompressionConfig, DenseEngine,
                                      PermuteEngine)
         from repro.core import erdos_renyi_adjacency, laplacian_mixing
-        from repro.sharding.compat import shard_map, set_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         spec = laplacian_mixing(erdos_renyi_adjacency(m, 0.5, seed=11))
         tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (m, 37, 5)),
@@ -486,11 +486,11 @@ def test_int8_compression_dense_and_ppermute_tolerance_contract():
         md, _ = DenseEngine(spec, compression=comp).mix_ef(tree, ef, t0)
 
         eng = PermuteEngine(spec, agent_axes=("data",), compression=comp)
-        fn = shard_map(lambda t, r: eng.mix_ef(t, r, t0), mesh=mesh,
-                       in_specs=(P("data"), P("data")),
-                       out_specs=(P("data"), P("data")),
-                       axis_names={"data"}, check_vma=False)
-        with set_mesh(mesh):
+        fn = jax.shard_map(lambda t, r: eng.mix_ef(t, r, t0), mesh=mesh,
+                           in_specs=(P("data"), P("data")),
+                           out_specs=(P("data"), P("data")),
+                           axis_names={"data"}, check_vma=False)
+        with jax.set_mesh(mesh):
             mp, efp = jax.jit(fn)(tree, ef)
 
         bound = max(float(jnp.max(jnp.abs(l)))
@@ -517,9 +517,9 @@ def test_dp_noise_dense_reference_tolerance_contract():
         from jax.sharding import PartitionSpec as P
         from repro.consensus import DenseEngine, PermuteEngine
         from repro.core import erdos_renyi_adjacency, laplacian_mixing
-        from repro.sharding.compat import shard_map, set_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         spec = laplacian_mixing(erdos_renyi_adjacency(m, 0.5, seed=11))
         X = jax.random.normal(jax.random.PRNGKey(0), (m, 64))
@@ -529,12 +529,12 @@ def test_dp_noise_dense_reference_tolerance_contract():
         for impl in ("ppermute", "psum"):
             eng = PermuteEngine(spec, agent_axes=("data",),
                                 dp_sigma=sigma, impl=impl)
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda t, ii: eng.mix(t, dp_key=jax.random.PRNGKey(5),
                                       agent_index=ii[0]),
                 mesh=mesh, in_specs=(P("data"), P("data")),
                 out_specs=P("data"), axis_names={"data"}, check_vma=False)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 got = jax.jit(fn)(X, ids)
             diff = np.abs(np.asarray(got) - np.asarray(ref))
             assert diff.max() > 1e-5            # noise actually applied

@@ -30,17 +30,18 @@ def test_ring_mix_matches_dense_mixing_matrix():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.sharding.collectives import ring_mix_leaf
-        from repro.sharding.compat import shard_map, set_mesh
         from repro.core import ring_mixing
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         spec = ring_mixing(m, self_weight=1/3)
         x = jax.random.normal(jax.random.PRNGKey(0), (m, 16))
-        fn = shard_map(lambda t: ring_mix_leaf(t, ("data",), 1/3),
+        fn = jax.shard_map(lambda t: ring_mix_leaf(t, ("data",), 1/3),
                            mesh=mesh, in_specs=P("data"),
-                           out_specs=P("data"), axis_names={"data"})
-        with set_mesh(mesh):
+                           out_specs=P("data"), axis_names={"data"},
+                           check_vma=False)
+        with jax.set_mesh(mesh):
             got = jax.jit(fn)(x)
         want = jnp.asarray(spec.matrix, jnp.float32) @ x
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -58,13 +59,13 @@ def test_distributed_interact_matches_reference_trajectory():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.core import ring_mixing, mix_pytree
-        from repro.sharding.compat import set_mesh
         from repro.sharding.partition import tree_shardings
         from repro.train.bilevel_lm import BilevelHyper, local_grads
         from repro.train.step import (InteractConfig, init_train_state,
                                       make_train_step, train_state_specs)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("smollm-360m").reduced(vocab_size=128, num_layers=2,
                                                 dtype="float32")
         hyper = BilevelHyper(mu_g=0.5, neumann_k=2, lipschitz_g=4.0,
@@ -80,7 +81,7 @@ def test_distributed_interact_matches_reference_trajectory():
             state, tree_shardings(mesh, train_state_specs(state, mesh)))
         dtok = jax.device_put(tokens, NamedSharding(mesh, P("data")))
         step = make_train_step(cfg, mesh, icfg)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jstep = jax.jit(step)
             for _ in range(2):
                 dstate, _ = jstep(dstate, dtok)
@@ -133,10 +134,10 @@ def test_dryrun_single_combo_small_mesh():
         from repro.launch.dryrun import parse_collectives
         from repro.launch.serving import make_serve_step
         from repro.models import model as M
-        from repro.sharding.compat import set_mesh
         from repro.sharding.partition import cache_specs, tree_specs, tree_shardings
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("mixtral-8x7b").reduced(vocab_size=128)
         params_sh = jax.eval_shape(
             lambda k: M.init_params(cfg, k, with_head=True),
@@ -149,7 +150,7 @@ def test_dryrun_single_combo_small_mesh():
             p_shard, NamedSharding(mesh, P("data")), c_shard,
             NamedSharding(mesh, P())),
             out_shardings=(NamedSharding(mesh, P("data")), c_shard))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(
                 params_sh, jax.ShapeDtypeStruct((8, 1), jnp.int32), cache,
                 jax.ShapeDtypeStruct((), jnp.int32))
@@ -213,7 +214,6 @@ def test_two_process_init_and_cross_process_collectives():
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.launch import distributed as D
-        from repro.sharding.compat import shard_map, set_mesh
 
         assert D.initialize_from_env()
         assert jax.process_count() == 2
@@ -223,20 +223,20 @@ def test_two_process_init_and_cross_process_collectives():
         x = D.shard_host_tree(mesh, host, 4)
         gather = D._make_gather(mesh)
 
-        fn = shard_map(lambda t: jax.lax.psum(t, "data"), mesh=mesh,
-                       in_specs=(P("data"),), out_specs=P(),
-                       axis_names=set(mesh.axis_names), check_vma=False)
-        with set_mesh(mesh):
+        fn = jax.shard_map(lambda t: jax.lax.psum(t, "data"), mesh=mesh,
+                           in_specs=(P("data"),), out_specs=P(),
+                           axis_names=set(mesh.axis_names), check_vma=False)
+        with jax.set_mesh(mesh):
             got = np.asarray(jax.device_get(jax.jit(fn)(x)))
         np.testing.assert_allclose(got, host.sum(axis=0, keepdims=True),
                                    atol=1e-6)
 
         perm = [(i, (i + 1) % 4) for i in range(4)]
-        fn2 = shard_map(lambda t: jax.lax.ppermute(t, "data", perm),
-                        mesh=mesh, in_specs=(P("data"),),
-                        out_specs=P("data"),
-                        axis_names=set(mesh.axis_names), check_vma=False)
-        with set_mesh(mesh):
+        fn2 = jax.shard_map(lambda t: jax.lax.ppermute(t, "data", perm),
+                            mesh=mesh, in_specs=(P("data"),),
+                            out_specs=P("data"),
+                            axis_names=set(mesh.axis_names), check_vma=False)
+        with jax.set_mesh(mesh):
             got2 = np.asarray(gather(jax.jit(fn2)(x)))
         np.testing.assert_allclose(got2, np.roll(host, 1, axis=0),
                                    atol=1e-6)
@@ -313,13 +313,13 @@ def test_agents_per_pod_mode():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
-        from repro.sharding.compat import set_mesh
         from repro.sharding.partition import tree_shardings
         from repro.train.bilevel_lm import BilevelHyper
         from repro.train.step import (InteractConfig, init_train_state,
                                       make_train_step, train_state_specs)
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get_config("smollm-360m").reduced(vocab_size=128, num_layers=2,
                                                 dtype="float32")
         hyper = BilevelHyper(mu_g=0.5, neumann_k=2, lipschitz_g=4.0,
@@ -337,7 +337,7 @@ def test_agents_per_pod_mode():
                                     cfg.vocab_size)
         dtok = jax.device_put(tokens, NamedSharding(mesh, P("pod")))
         step = make_train_step(cfg, mesh, icfg, agent_mode="pods")
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jstep = jax.jit(step)
             for _ in range(2):
                 dstate, metrics = jstep(dstate, dtok)
@@ -356,7 +356,6 @@ def test_distributed_svr_interact_runs():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
-        from repro.sharding.compat import set_mesh
         from repro.sharding.partition import tree_shardings
         from repro.train.bilevel_lm import BilevelHyper
         from repro.train.step import InteractConfig
@@ -364,7 +363,8 @@ def test_distributed_svr_interact_runs():
                                           make_svr_train_step,
                                           svr_train_state_specs)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("smollm-360m").reduced(vocab_size=128, num_layers=2,
                                                 dtype="float32")
         hyper = BilevelHyper(mu_g=0.5, neumann_k=2, lipschitz_g=4.0,
@@ -378,7 +378,7 @@ def test_distributed_svr_interact_runs():
                                     cfg.vocab_size)
         tokens = jax.device_put(tokens, NamedSharding(mesh, P("data")))
         step = make_svr_train_step(cfg, mesh, icfg, q=3)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jstep = jax.jit(step)
             refreshes = []
             for _ in range(4):
@@ -401,7 +401,6 @@ def test_compressed_and_dp_consensus():
         from jax.sharding import PartitionSpec as P
         from repro.sharding.collectives import (ring_mix_leaf, quantize_int8,
                                                 dequantize_int8)
-        from repro.sharding.compat import shard_map, set_mesh
         from repro.core import ring_mixing
 
         # quantize/dequantize round-trip error bounded by scale/2
@@ -410,17 +409,18 @@ def test_compressed_and_dp_consensus():
         err = jnp.max(jnp.abs(dequantize_int8(q, s) - x))
         assert float(err) <= float(s) * 0.5 + 1e-6
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         spec = ring_mixing(m, self_weight=1/3)
         X = jax.random.normal(jax.random.PRNGKey(1), (m, 32))
 
         def run(**kw):
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda t: ring_mix_leaf(t, ("data",), 1/3, **kw),
                 mesh=mesh, in_specs=P("data"), out_specs=P("data"),
                 axis_names={"data"}, check_vma=False)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 return jax.jit(fn)(X)
 
         exact = jnp.asarray(spec.matrix, jnp.float32) @ X

@@ -137,13 +137,13 @@ def test_ppermute_checkpoint_resume_bitwise():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import latest_step, restore_step, save_step
         from repro.configs import get_config
-        from repro.sharding.compat import set_mesh
         from repro.sharding.partition import tree_shardings
         from repro.train.bilevel_lm import BilevelHyper
         from repro.train.step import (InteractConfig, init_train_state,
                                       make_train_step, train_state_specs)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("smollm-360m").reduced(
             vocab_size=128, num_layers=2, dtype="float32")
         hyper = BilevelHyper(mu_g=0.5, neumann_k=2, lipschitz_g=4.0,
@@ -159,7 +159,7 @@ def test_ppermute_checkpoint_resume_bitwise():
         def advance(state, n):
             dstate = jax.device_put(state, shards)
             dtok = jax.device_put(tokens, NamedSharding(mesh, P("data")))
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 jstep = jax.jit(step)
                 for _ in range(n):
                     dstate, _ = jstep(dstate, dtok)

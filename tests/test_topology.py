@@ -295,10 +295,10 @@ def test_ppermute_matches_dense_under_stream():
         from jax.sharding import PartitionSpec as P
         from repro.consensus import DenseEngine, PermuteEngine
         from repro.core import erdos_renyi_adjacency, laplacian_mixing
-        from repro.sharding.compat import shard_map, set_mesh
         from repro.topology import TopologyProcessConfig, attach_topology
 
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         m = 8
         spec = laplacian_mixing(erdos_renyi_adjacency(m, 0.5, seed=11))
         proc = TopologyProcessConfig(kind="link-failure", p=0.4, period=12)
@@ -308,11 +308,11 @@ def test_ppermute_matches_dense_under_stream():
         tree = {"w": jax.random.normal(jax.random.PRNGKey(0), (m, 13, 3)),
                 "b": jax.random.normal(jax.random.PRNGKey(1), (m, 29))}
         for t in (0, 3, 7, 11):
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda tr: eng.mix(tr, matrix=eng.topology.matrix_at(t)),
                 mesh=mesh, in_specs=P("data"), out_specs=P("data"),
                 axis_names={"data"}, check_vma=False)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 got = jax.jit(fn)(tree)
             want = dense.mix(tree, matrix=dense.topology.matrix_at(t))
             for a, b in zip(jax.tree_util.tree_leaves(got),
