@@ -70,6 +70,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.byzantine import (ByzantineConfig, apply_attack, byzantine_mask,
                              make_attack, robust_combine)
 from repro.consensus.compress import CompressionConfig, make_compressor
@@ -559,19 +560,21 @@ def consensus_descent_and_track(
     Returns ``(x_new, y_new, u_new, v_new, p_new, ef_new, aux)``.
     """
     wire = ef is not None or getattr(engine, "wire_active", False)
-    if wire:
-        x_new, u_mixed, ef_new = engine.step1_step3(
-            x, u, p_prev, p_prev, alpha, t=t, ef=ef, dp_key=dp_key,
-            agent_index=agent_index)
-    else:
-        x_new, u_mixed = engine.step1_step3(x, u, p_prev, p_prev, alpha,
-                                            t=t, dp_key=dp_key,
-                                            agent_index=agent_index)
-        ef_new = ef
+    with tracing.scope(tracing.MIX):
+        if wire:
+            x_new, u_mixed, ef_new = engine.step1_step3(
+                x, u, p_prev, p_prev, alpha, t=t, ef=ef, dp_key=dp_key,
+                agent_index=agent_index)
+        else:
+            x_new, u_mixed = engine.step1_step3(x, u, p_prev, p_prev, alpha,
+                                                t=t, dp_key=dp_key,
+                                                agent_index=agent_index)
+            ef_new = ef
     y_new = jax.tree_util.tree_map(
         lambda yy, vv: (_f32(yy) - beta * _f32(vv)).astype(yy.dtype), y, v)
 
-    p_new, v_new, aux = grads_fn(x_new, y_new)
+    with tracing.scope(tracing.LOCAL_GRAD):
+        p_new, v_new, aux = grads_fn(x_new, y_new)
 
     u_new = jax.tree_util.tree_map(
         lambda mu, pn, pp: (_f32(mu) + (_f32(pn) - _f32(pp))

@@ -27,6 +27,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.consensus import consensus_descent_and_track, init_ef
 from repro.core.bilevel import AgentData, BilevelProblem
 from repro.core.consensus import MixingSpec
@@ -56,6 +57,7 @@ def _bcast(tree, m):
         lambda leaf: jnp.broadcast_to(leaf, (m,) + leaf.shape), tree)
 
 
+@tracing.scoped(tracing.INIT)
 def init_gt_dsgd_state(problem: BilevelProblem, hg_cfg: HypergradConfig,
                        x0, y0, data: AgentData, key: jax.Array,
                        batch_size: int, compression=None,
@@ -125,6 +127,7 @@ class DsgdState(NamedTuple):
     guard: object = None  # divergence-guard counters {"tripped", "last_good"}
 
 
+@tracing.scoped(tracing.INIT)
 def init_dsgd_state(x0, y0, m: int, key: jax.Array,
                     compression=None, guard=None) -> DsgdState:
     x = _bcast(x0, m)
@@ -152,13 +155,16 @@ def dsgd_step(problem: BilevelProblem, hg_cfg: HypergradConfig,
               if hasattr(engine, "topology_matrix") else None)
     if state.ef is not None or getattr(engine, "wire_active", False):
         ef_x = None if state.ef is None else state.ef.get("x")
-        x_mixed, ef_x_new = engine.mix_ef(state.x, ef_x, state.t,
-                                          matrix=matrix)
+        with tracing.scope(tracing.MIX):
+            x_mixed, ef_x_new = engine.mix_ef(state.x, ef_x, state.t,
+                                              matrix=matrix)
         ef_new = None if state.ef is None else {"x": ef_x_new}
     else:
         # mix_ef with no wire state is bitwise ``mix`` — routed through it
         # so an attached CommsLedger records D-SGD's single x stream too.
-        x_mixed, _ = engine.mix_ef(state.x, None, state.t, matrix=matrix)
+        with tracing.scope(tracing.MIX):
+            x_mixed, _ = engine.mix_ef(state.x, None, state.t,
+                                       matrix=matrix)
         ef_new = state.ef
     x_new = jax.tree_util.tree_map(
         lambda mx, g: mx - alpha * g, x_mixed, p)

@@ -40,6 +40,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.consensus import as_engine, consensus_descent_and_track, init_ef
 from repro.core.bilevel import AgentData, BilevelProblem
 from repro.core.consensus import MixingSpec
@@ -83,6 +84,7 @@ def _agent_gradients(problem: BilevelProblem, hg_cfg: HypergradConfig,
     return p, v
 
 
+@tracing.scoped(tracing.INIT)
 def init_state(problem: BilevelProblem, hg_cfg: HypergradConfig,
                x0, y0, data: AgentData,
                compression=None, guard=None) -> InteractState:

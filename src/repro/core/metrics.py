@@ -18,6 +18,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.bilevel import AgentData, BilevelProblem
 from repro.hypergrad import HypergradConfig, hypergradient
 
@@ -54,11 +55,24 @@ def solve_inner(problem: BilevelProblem, x, y0, batch,
     return jax.lax.fori_loop(0, steps, body, y0)
 
 
-@partial(jax.jit, static_argnums=(0, 1, 4, 5))
 def convergence_metric(problem: BilevelProblem, hg_cfg: HypergradConfig,
                        x_stack, y_stack, inner_steps: int, inner_lr: float,
                        data: AgentData) -> MetricReport:
-    """Compute M_t for stacked per-agent iterates (leading axis m)."""
+    """Compute M_t for stacked per-agent iterates (leading axis m).
+
+    Jitted (``problem``, ``hg_cfg``, ``inner_steps`` and ``inner_lr``
+    static); the dispatch is the ``repro.eq11`` host span.
+    """
+    with tracing.host_span(tracing.EQ11):
+        return _convergence_metric(problem, hg_cfg, x_stack, y_stack,
+                                   inner_steps, inner_lr, data)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 4, 5))
+@tracing.scoped(tracing.EQ11)
+def _convergence_metric(problem: BilevelProblem, hg_cfg: HypergradConfig,
+                        x_stack, y_stack, inner_steps: int, inner_lr: float,
+                        data: AgentData) -> MetricReport:
     m = jax.tree_util.tree_leaves(x_stack)[0].shape[0]
     x_bar = _tree_mean_over_agents(x_stack)
 
@@ -128,6 +142,7 @@ def _masked_agent_sum(tree, num_active):
     return jax.lax.fori_loop(0, m_pad, body, zero)
 
 
+@tracing.scoped(tracing.EQ11)
 def masked_convergence_metric(problem: BilevelProblem,
                               hg_cfg: HypergradConfig,
                               x_stack, y_stack, inner_steps: int,

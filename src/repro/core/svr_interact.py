@@ -31,6 +31,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.consensus import consensus_descent_and_track, init_ef
 from repro.core.bilevel import AgentData, BilevelProblem
 from repro.core.consensus import MixingSpec
@@ -93,6 +94,7 @@ def _minibatch_grads(problem, hg_cfg, x, y, data: AgentData, key, batch_size):
     return p, v
 
 
+@tracing.scoped(tracing.INIT)
 def init_svr_state(problem: BilevelProblem, hg_cfg: HypergradConfig,
                    x0, y0, data: AgentData, key: jax.Array,
                    compression=None, guard=None) -> SvrState:

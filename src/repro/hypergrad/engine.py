@@ -34,6 +34,7 @@ from typing import Callable
 
 import jax
 
+from repro import tracing
 from repro.hypergrad.config import HypergradConfig
 from repro.hypergrad.operator import HypergradStats, flat_dot, tree_sub
 
@@ -147,7 +148,9 @@ def hypergradient_with_stats(
     """
     engine = get_backend(cfg.resolve_backend())
     gx, gy = jax.grad(f, argnums=(0, 1))(x, y, *f_args)
-    z, stats = engine.solve(g, x, y, gy, cfg, g_args, key, inner_hess_yy)
+    with tracing.scope(tracing.HYPERGRAD):
+        z, stats = engine.solve(g, x, y, gy, cfg, g_args, key,
+                                inner_hess_yy)
     correction = hvp_xy(g, x, y, z, *g_args)
     p = tree_sub(gx, correction)
     stats = stats._replace(hvp_count=stats.hvp_count + 1,   # H_xy cross term

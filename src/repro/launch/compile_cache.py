@@ -20,7 +20,14 @@ REPO_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def enable_compile_cache() -> str:
-    """Turn the persistent cache on; returns the directory in use."""
+    """Turn the persistent cache on; returns the directory in use.
+
+    The cache key keeps the ops' metadata, whose ``op_name`` carries the
+    program's layer scopes (``repro.tracing``): by default JAX drops it
+    from the key and would serve a program cached before a scope existed
+    in the scoped one's place, and a trace would not show the scope.
+    """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     outside = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if outside:
         return outside

@@ -33,6 +33,7 @@ from typing import Any, Callable, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.consensus import make_engine
 from repro.solvers.config import SolverConfig
 
@@ -256,7 +257,8 @@ class SolverBase:
             from repro.byzantine import guard_param_step
             self._param_step = guard_param_step(self._param_step,
                                                 self.config.guard)
-        raw = self._make_step(problem, hg_cfg, engine, n)
+        raw = tracing.scoped(tracing.STEP)(
+            self._make_step(problem, hg_cfg, engine, n))
         self._raw_step = raw
         self._step_fn = jax.jit(raw, donate_argnums=0)
 
@@ -368,13 +370,14 @@ class SolverBase:
         """
         if self._run_fn is None:
             raise RuntimeError("call init()/build() before run()")
-        if checkpoint_every:
-            from repro.resilience import run_resumable
-            state, _, _ = run_resumable(
-                self, state, data, num_steps,
-                checkpoint_every=checkpoint_every, ckpt_dir=ckpt_dir)
-            return state
-        return self._run_fn(state, data, num_steps)
+        with tracing.host_span(tracing.RUN):
+            if checkpoint_every:
+                from repro.resilience import run_resumable
+                state, _, _ = run_resumable(
+                    self, state, data, num_steps,
+                    checkpoint_every=checkpoint_every, ckpt_dir=ckpt_dir)
+                return state
+            return self._run_fn(state, data, num_steps)
 
     def run_traced(self, state, data, num_steps: int, record_every: int = 0,
                    metric_fn=None, *, checkpoint_every: int | None = None,
