@@ -20,6 +20,7 @@ ARCH_IDS = (
     "paligemma-3b",
     "dbrx-132b",
     "llama3.2-3b",
+    "jamba2-3b",
 )
 
 _MODULES = {
@@ -33,6 +34,7 @@ _MODULES = {
     "paligemma-3b": "paligemma_3b",
     "dbrx-132b": "dbrx_132b",
     "llama3.2-3b": "llama3_2_3b",
+    "jamba2-3b": "jamba2_3b",
     "interact-meta-mlp": "interact_meta",
 }
 

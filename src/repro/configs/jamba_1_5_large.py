@@ -2,6 +2,8 @@
 
 72L d_model=8192 64H (GQA kv=8) d_ff=24576 vocab=65536.  [arXiv:2403.19887]
 MoE applied every other layer (moe_every=2), attention 1 layer in 8.
+Mamba mixer as HF Jamba: dt_rank "auto" = ceil(8192 / 16) = 512 and the
+dt/B/C RMSNorms (models/mamba.py).
 """
 from repro.models.base import ArchConfig
 
@@ -24,4 +26,5 @@ CONFIG = ArchConfig(
     mamba_d_state=16,
     mamba_d_conv=4,
     mamba_expand=2,
+    mamba_dt_rank=512,
 )

@@ -25,6 +25,7 @@ import numpy as np
 __all__ = [
     "AgentData",
     "BilevelProblem",
+    "HeadSplit",
     "MLPMetaProblem",
     "make_synthetic_agents",
     "init_mlp_backbone",
@@ -45,6 +46,23 @@ class AgentData:
 
 
 @dataclasses.dataclass(frozen=True)
+class HeadSplit:
+    """A problem whose y is a readout head on features of x:
+
+        f(x, y, (inputs, labels)) = outer(features(x, inputs), y, labels)
+        g(x, y, (inputs, labels)) = inner(features(x, inputs), y, labels)
+
+    H_yy(g) then touches x only through the features, so the inverse can
+    be applied in head space on features computed once per call
+    (``repro.hypergrad.head``).
+    """
+
+    features: Callable  # (x, inputs) -> features
+    outer: Callable     # (features, y, labels) -> scalar
+    inner: Callable     # (features, y, labels) -> scalar, ridge included
+
+
+@dataclasses.dataclass(frozen=True)
 class BilevelProblem:
     """f(x, y, batch) outer loss, g(x, y, batch) inner loss.
 
@@ -57,6 +75,11 @@ class BilevelProblem:
     hypergradient backend uses it instead of materialising H_yy through
     d_y automatic-differentiation HVPs (see docs/HYPERGRAD.md); every
     other backend ignores it, so it is purely an opt-in fast path.
+
+    ``head`` (a ``HeadSplit``) marks y as a readout head on features of
+    x.  The ``neumann-linearized`` hypergradient backend uses it to run
+    the series in head space on features computed once per call
+    (``repro.hypergrad.head``); every other backend ignores it.
     """
 
     outer: Callable  # f(x, y, (inputs, labels)) -> scalar
@@ -64,6 +87,7 @@ class BilevelProblem:
     mu_g: float      # strong-convexity modulus of g in y
     lipschitz_g: float  # gradient-Lipschitz bound L_g for the Neumann scale
     inner_hess_yy: Callable | None = None  # optional closed-form flat H_yy
+    head: HeadSplit | None = None  # optional features/head factorisation
 
 
 def pad_agent_data(data: AgentData, pad_to: int) -> AgentData:

@@ -44,7 +44,7 @@ from repro import tracing
 from repro.consensus import as_engine, consensus_descent_and_track, init_ef
 from repro.core.bilevel import AgentData, BilevelProblem
 from repro.core.consensus import MixingSpec
-from repro.hypergrad import HypergradConfig, hypergradient
+from repro.hypergrad import HypergradConfig, local_gradients
 
 __all__ = [
     "InteractState",
@@ -75,12 +75,8 @@ def _per_agent_batch(data: AgentData):
 def _agent_gradients(problem: BilevelProblem, hg_cfg: HypergradConfig,
                      x, y, inner_batch, outer_batch, key=None):
     """(p_i, v_i) for a single agent (no leading agent dim here)."""
-    p = hypergradient(
-        problem.outer, problem.inner, x, y, hg_cfg,
-        f_args=(outer_batch,), g_args=(inner_batch,), key=key,
-        inner_hess_yy=problem.inner_hess_yy,
-    )
-    v = jax.grad(problem.inner, argnums=1)(x, y, inner_batch)
+    p, v, _ = local_gradients(problem, hg_cfg, x, y, outer_batch,
+                              inner_batch, key)
     return p, v
 
 

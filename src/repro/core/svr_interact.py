@@ -35,7 +35,7 @@ from repro import tracing
 from repro.consensus import consensus_descent_and_track, init_ef
 from repro.core.bilevel import AgentData, BilevelProblem
 from repro.core.consensus import MixingSpec
-from repro.hypergrad import HypergradConfig, hypergradient
+from repro.hypergrad import HypergradConfig, local_gradients
 
 __all__ = ["SvrState", "init_svr_state", "per_agent_keys",
            "svr_interact_step", "make_svr_interact_step"]
@@ -76,10 +76,7 @@ def _sample_batch(key, data_x, data_y, batch_size):
 def _full_grads(problem, hg_cfg, x, y, data: AgentData, key):
     inner_b = (data.inner_x, data.inner_y)
     outer_b = (data.outer_x, data.outer_y)
-    p = hypergradient(problem.outer, problem.inner, x, y, hg_cfg,
-                      f_args=(outer_b,), g_args=(inner_b,), key=key,
-                      inner_hess_yy=problem.inner_hess_yy)
-    v = jax.grad(problem.inner, argnums=1)(x, y, inner_b)
+    p, v, _ = local_gradients(problem, hg_cfg, x, y, outer_b, inner_b, key)
     return p, v
 
 
@@ -87,10 +84,8 @@ def _minibatch_grads(problem, hg_cfg, x, y, data: AgentData, key, batch_size):
     k_in, k_out, k_neu = jax.random.split(key, 3)
     inner_b = _sample_batch(k_in, data.inner_x, data.inner_y, batch_size)
     outer_b = _sample_batch(k_out, data.outer_x, data.outer_y, batch_size)
-    p = hypergradient(problem.outer, problem.inner, x, y, hg_cfg,
-                      f_args=(outer_b,), g_args=(inner_b,), key=k_neu,
-                      inner_hess_yy=problem.inner_hess_yy)
-    v = jax.grad(problem.inner, argnums=1)(x, y, inner_b)
+    p, v, _ = local_gradients(problem, hg_cfg, x, y, outer_b, inner_b,
+                              k_neu)
     return p, v
 
 

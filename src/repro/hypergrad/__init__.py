@@ -28,6 +28,7 @@ from repro.hypergrad.engine import (
     hvp_yy,
     hypergradient,
     hypergradient_with_stats,
+    local_gradients,
     measure_counts,
     measure_problem_counts,
     register_backend,
@@ -53,6 +54,7 @@ __all__ = [
     "hvp_yy",
     "hypergradient",
     "hypergradient_with_stats",
+    "local_gradients",
     "measure_counts",
     "measure_problem_counts",
     "neumann_inverse_apply",

@@ -47,6 +47,7 @@ __all__ = [
     "hvp_xy",
     "hypergradient",
     "hypergradient_with_stats",
+    "local_gradients",
     "measure_counts",
     "measure_problem_counts",
 ]
@@ -83,6 +84,17 @@ class HypergradEngine:
     def solve(self, g: Callable, x, y, b, cfg: HypergradConfig,
               g_args: tuple, key, inner_hess_yy: Callable | None = None):
         raise NotImplementedError
+
+    def local_gradients(self, problem, x, y, cfg: HypergradConfig,
+                        f_batch, g_batch, key):
+        """``(p, v, stats)`` of one agent: eq. (5) through ``solve`` and
+        grad_y g.  A backend that can use more of the problem's structure
+        than its losses overrides this."""
+        p, stats = hypergradient_with_stats(
+            problem.outer, problem.inner, x, y, cfg, f_args=(f_batch,),
+            g_args=(g_batch,), key=key, inner_hess_yy=problem.inner_hess_yy)
+        v = jax.grad(problem.inner, argnums=1)(x, y, g_batch)
+        return p, v, stats
 
 
 _REGISTRY: dict[str, HypergradEngine] = {}
@@ -180,6 +192,18 @@ def hypergradient(
                                     g_args=g_args, key=key,
                                     inner_hess_yy=inner_hess_yy)
     return p
+
+
+def local_gradients(problem, cfg: HypergradConfig, x, y, f_batch, g_batch,
+                    key: jax.Array | None = None):
+    """``(p, v, stats)``: one agent's hypergradient of eq. (5), its inner
+    gradient grad_y g, and the counted evaluations, from the backend
+    ``cfg`` selects.  ``problem`` is a ``repro.core.bilevel.BilevelProblem``;
+    ``f_batch`` / ``g_batch`` are its outer / inner batches.  The
+    ``neumann-linearized`` backend runs the series in head space when the
+    problem carries a ``HeadSplit`` (``repro.hypergrad.head``)."""
+    engine = get_backend(cfg.resolve_backend())
+    return engine.local_gradients(problem, x, y, cfg, f_batch, g_batch, key)
 
 
 def measure_counts(

@@ -165,3 +165,15 @@ class NeumannLinearizedEngine(HypergradEngine):
         stats = HypergradStats.zero()._replace(
             hvp_count=count, grad_count=jnp.int32(1))
         return unravel(z_flat), stats
+
+    def local_gradients(self, problem, x, y, cfg: HypergradConfig,
+                        f_batch, g_batch, key):
+        """With a ``HeadSplit`` the deterministic chain runs on features
+        computed once per call (``repro.hypergrad.head``)."""
+        split = getattr(problem, "head", None)
+        if split is None or cfg.stochastic_k:
+            return super().local_gradients(problem, x, y, cfg, f_batch,
+                                           g_batch, key)
+        from repro.hypergrad.head import head_hypergradient_with_stats
+        return head_hypergradient_with_stats(split, x, y, cfg,
+                                             f_batch, g_batch)

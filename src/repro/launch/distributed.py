@@ -11,10 +11,13 @@ closes that gap:
   env vars the localhost driver (scripts/launch_local.py) exports.
 * ``agent_mesh`` — the global device mesh over ``jax.devices()`` (which
   spans every process after initialize), agents on the ``data`` axis.
-* ``run_section6`` — the paper's Section-6 synthetic instance stepped by
-  the registry INTERACT solver whose raw step body is wrapped in a
-  *full-manual* shard_map over the mesh, with the eq.-11 stationarity
-  metric recorded host-side and a
+* ``MeshRunner`` — any built registry solver (any ``BilevelProblem``)
+  stepped on the mesh: its raw step body wrapped in a *full-manual*
+  shard_map, one agent per ``data`` row, state initialised on the mesh
+  from one initial point or put there from the host.
+* ``run_section6`` — the paper's Section-6 synthetic instance through a
+  ``MeshRunner``, with the eq.-11 stationarity metric recorded
+  host-side and a
   ``CommsLedger`` measuring the bytes the compiled program actually
   ships (docs/DISTRIBUTED.md).
 
@@ -38,6 +41,7 @@ from repro.launch.mesh import make_production_mesh
 
 __all__ = [
     "DistributedConfig",
+    "MeshRunner",
     "agent_mesh",
     "initialize",
     "initialize_from_env",
@@ -182,6 +186,95 @@ def _digest(host_tree) -> str:
     return h.hexdigest()
 
 
+class MeshRunner:
+    """A built registry solver stepped on the device mesh, one agent per
+    ``data`` row: its raw step inside a full-manual ``shard_map``.
+
+    Runs any ``BilevelProblem`` the solver was built for.  State and data
+    leaves carry the agent axis first and are sharded ``P("data")``
+    (scalars such as the step counter replicated), so inside the map each
+    device holds its agent's slice and the mesh consensus backends
+    (``ppermute``, ``allgather``) exchange across devices.
+
+    ``batch_of(data, t) -> data`` picks the step's batch from ``data`` by
+    the state's step counter ``t`` (a pool of batches resident on the
+    devices, cycled with no host feed); ``None`` steps on ``data`` as
+    given.
+    """
+
+    def __init__(self, solver, num_agents: int, *, batch_of=None,
+                 mesh=None):
+        if getattr(solver, "_raw_step", None) is None:
+            raise ValueError("build the solver (solver.build / init) first")
+        self.solver = solver
+        self.m = int(num_agents)
+        self.mesh = mesh if mesh is not None else agent_mesh(self.m)
+        self.batch_of = batch_of
+        self._runs: dict = {}
+
+    def put(self, host_tree):
+        """Host tree -> global arrays on the mesh (``shard_host_tree``)."""
+        return shard_host_tree(self.mesh, host_tree, self.m)
+
+    def agent_specs(self, tree):
+        """``P("data")`` for agent-stacked leaves, ``P()`` for scalars."""
+        return jax.tree_util.tree_map(
+            lambda l: P("data") if getattr(l, "ndim", 0) else P(), tree)
+
+    def smap(self, fn, in_specs, out_specs):
+        """``fn`` in a full-manual shard_map over this runner's mesh."""
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs,
+                             axis_names=set(self.mesh.axis_names),
+                             check_vma=False)
+
+    def _batch(self, data, t):
+        return data if self.batch_of is None else self.batch_of(data, t)
+
+    def init_fn(self, point_shapes, data_shapes):
+        """Jitted ``init(point, data) -> state``: the solver's
+        ``_init_state`` on every agent from the one initial point
+        ``point = (x0, y0)``, replicated on every device (``P()``)."""
+        solver = self.solver
+
+        def local(point, d):
+            x0, y0 = point
+            return solver._init_state(None, solver._problem, solver._hg_cfg,
+                                      x0, y0, self._batch(d, 0))
+
+        pspec = jax.tree_util.tree_map(lambda _: P(), point_shapes)
+        dspec = self.agent_specs(data_shapes)
+        local_data = jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct((1,) + l.shape[1:], l.dtype),
+            data_shapes)
+        sspec = self.agent_specs(jax.eval_shape(local, point_shapes,
+                                                local_data))
+        return jax.jit(self.smap(local, (pspec, dspec), sspec))
+
+    def run(self, state, data, num_steps: int):
+        """``num_steps`` solver steps in one jitted scan (state donated).
+
+        Returns ``(state, t)``: ``t`` is a copy of the step counter in a
+        buffer of its own, which a caller may wait on after the state has
+        been donated to the next call.
+        """
+        fn = self._runs.get(num_steps)
+        if fn is None:
+            raw = self.solver._raw_step
+            sspec = self.agent_specs(state)
+            dspec = self.agent_specs(data)
+            step = self.smap(lambda s, d: raw(s, self._batch(d, s.t)),
+                             (sspec, dspec), sspec)
+
+            def chunk(s, d):
+                out, _ = jax.lax.scan(lambda c, _: (step(c, d), None), s,
+                                      xs=None, length=num_steps)
+                return out, jnp.copy(out.t)
+
+            fn = self._runs[num_steps] = jax.jit(chunk, donate_argnums=0)
+        return fn(state, data)
+
+
 def run_section6(*, num_agents: int = 8, num_steps: int = 30,
                  record_every: int = 10, backend: str = "allgather",
                  compression=None, communication_interval: int = 1,
@@ -238,27 +331,11 @@ def run_section6(*, num_agents: int = 8, num_steps: int = 30,
     state = solver.init(None, problem, None, x0, y0, data)
     ledger = attach_ledger(solver._engine)
 
+    runner = MeshRunner(solver, m, mesh=mesh)
     host_state = jax.tree_util.tree_map(np.asarray, jax.device_get(state))
     host_data = jax.tree_util.tree_map(np.asarray, jax.device_get(data))
-    gstate = shard_host_tree(mesh, host_state, m)
-    gdata = shard_host_tree(mesh, host_data, m)
-
-    sspec = _spec_tree(host_state, m)
-    dspec = _spec_tree(host_data, m)
-    manual = set(mesh.axis_names)
-    raw = solver._raw_step
-    smap_step = jax.shard_map(raw, mesh=mesh, in_specs=(sspec, dspec),
-                              out_specs=sspec, axis_names=manual,
-                              check_vma=False)
-
-    def chunk(s, d, length):
-        def body(c, _):
-            return smap_step(c, d), None
-
-        out, _ = jax.lax.scan(body, s, xs=None, length=length)
-        return out
-
-    jchunk = jax.jit(chunk, static_argnums=2, donate_argnums=0)
+    gstate = runner.put(host_state)
+    gdata = runner.put(host_data)
     gather = _make_gather(mesh)
 
     def metric(gs) -> float:
@@ -277,15 +354,14 @@ def run_section6(*, num_agents: int = 8, num_steps: int = 30,
     with jax.set_mesh(mesh):
         for length in lengths:
             trace.append(metric(gstate))
-            gstate = jchunk(gstate, gdata, length)
+            gstate, _ = runner.run(gstate, gdata, length)
         final_metric = metric(gstate)
         trace.append(final_metric)
 
         engine = solver._engine
         xspec = _spec_tree(host_state.x, m)
-        mix_fn = jax.jit(jax.shard_map(
-            lambda t: engine.mix(t), mesh=mesh, in_specs=(xspec,),
-            out_specs=xspec, axis_names=manual, check_vma=False))
+        mix_fn = jax.jit(runner.smap(lambda t: engine.mix(t), (xspec,),
+                                     xspec))
         ledger.observe_latency(
             time_round_us(mix_fn, gstate.x, reps=latency_reps))
 

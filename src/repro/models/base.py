@@ -47,6 +47,7 @@ class ArchConfig:
     num_kv_heads: int = 4
     head_dim: int | None = None  # default: d_model // num_heads
     rope_theta: float = 10_000.0
+    use_rope: bool = True                 # jamba: attention has no positions
     qk_norm: bool = False                 # qwen3
     attn_logit_softcap: float | None = None  # gemma2: 50.0
     final_logit_softcap: float | None = None  # gemma2: 30.0
@@ -65,10 +66,13 @@ class ArchConfig:
 
     # --- hybrid / SSM ------------------------------------------------------
     attn_every: int = 0       # jamba: 8 => 1 attention layer per 8
+    attn_offset: int = 0      # position of that layer in the period (HF
+                              # attn_layer_offset: i % period == offset)
     mamba_seq_chunk: int = 0  # >0: chunked selective scan (perf, P7)
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
     mamba_expand: int = 2
+    mamba_dt_rank: int | None = None  # None: HF "auto", ceil(d_model / 16)
     rwkv_head_size: int = 64
 
     # --- modality frontend (stubs per spec) -------------------------------
@@ -78,6 +82,7 @@ class ArchConfig:
 
     # --- numerics / misc ---------------------------------------------------
     tie_embeddings: bool = True
+    scale_embeddings: bool = True  # x * sqrt(d_model) after the lookup
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
 
@@ -86,10 +91,43 @@ class ArchConfig:
     # "window": force all attention layers to the local window for the
     # sub-quadratic long_500k gate (documented deviation, DESIGN.md §4).
 
+    # --- one tensor-parallel rank's share of every layer -----------------
+    # ``tp_size`` ranks share each layer; this config holds one rank's
+    # part: num_heads / tp query heads (the kv heads split too, or, fewer
+    # than the ranks as under MQA, replicated), d_inner / tp Mamba
+    # channels, d_ff / tp FFN columns and vocab_size / tp vocabulary rows.
+    # Widths stay published; the absent ranks' partial sums (x_proj,
+    # out_proj, o_proj, FFN down) are left out, not stood in for.
+    tp_size: int = 1
+
     # -------------------------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def resolved_dt_rank(self) -> int:
+        return self.mamba_dt_rank or -(-self.d_model // 16)
+
+    @property
+    def held_heads(self) -> int:
+        return self.num_heads // self.tp_size
+
+    @property
+    def held_kv_heads(self) -> int:
+        return max(1, self.num_kv_heads // self.tp_size)
+
+    @property
+    def held_d_inner(self) -> int:
+        return self.mamba_expand * self.d_model // self.tp_size
+
+    @property
+    def held_d_ff(self) -> int:
+        return self.d_ff // self.tp_size
+
+    @property
+    def held_vocab(self) -> int:
+        return self.vocab_size // self.tp_size
 
     @property
     def is_attention_free(self) -> bool:
@@ -102,10 +140,10 @@ class ArchConfig:
 
         if self.family == "hybrid":
             # jamba: period of attn_every layers, one attention layer per
-            # period (at position 0); MoE on every ``moe_every``-th layer.
+            # period (at attn_offset); MoE on every ``moe_every``-th layer.
             period = []
             for i in range(self.attn_every):
-                mixer = "attn" if i == 0 else "mamba"
+                mixer = "attn" if i == self.attn_offset else "mamba"
                 ffn = "moe" if (self.num_experts and i % self.moe_every == 1 % self.moe_every) else "dense"
                 period.append(LayerSpec(mixer=mixer, ffn=ffn,
                                         sliding_window=self.sliding_window))
@@ -122,6 +160,20 @@ class ArchConfig:
         return (LayerSpec(mixer="attn", ffn=ffn,
                           sliding_window=self.sliding_window),)
 
+    def layer_runs(self) -> tuple[tuple[LayerSpec, int], ...]:
+        """The period as ``(spec, count)`` runs of equal consecutive
+        layers.  A run's parameters are stacked on a leading axis and the
+        forward steps them with one ``lax.scan`` (jamba2's period is 7
+        Mamba, 1 attention, 6 Mamba: three runs), so the program holds
+        one copy of each run's layer instead of one per layer."""
+        runs: list = []
+        for spec in self.layer_pattern():
+            if runs and runs[-1][0] == spec:
+                runs[-1][1] += 1
+            else:
+                runs.append([spec, 1])
+        return tuple((spec, n) for spec, n in runs)
+
     def num_periods(self) -> int:
         pat = self.layer_pattern()
         if self.num_layers % len(pat) != 0:
@@ -132,6 +184,15 @@ class ArchConfig:
 
     def validate(self) -> None:
         assert self.num_heads % max(self.num_kv_heads, 1) == 0, self.name
+        tp = self.tp_size
+        assert self.num_heads % tp == 0 and self.vocab_size % tp == 0, \
+            self.name
+        assert self.d_ff % tp == 0 and self.d_model * self.mamba_expand \
+            % tp == 0, self.name
+        assert self.num_kv_heads % tp == 0 or self.num_kv_heads < tp, \
+            self.name
+        if self.family == "hybrid":
+            assert 0 <= self.attn_offset < self.attn_every, self.name
         if self.num_experts:
             assert 0 < self.experts_per_token <= self.num_experts, self.name
         self.num_periods()
@@ -164,6 +225,8 @@ class ArchConfig:
             small["num_kv_heads"] = max(1, small["num_heads"] // min(ratio, small["num_heads"]))
         if self.family == "hybrid":
             small["attn_every"] = 2  # keep the attn/mamba mix, 1 period = 2 layers
+            small["attn_offset"] = min(self.attn_offset, 1)
             small["num_layers"] = 2
+            small["mamba_dt_rank"] = None  # auto from the cut d_model
         small.update(overrides)
         return dataclasses.replace(self, **small)

@@ -19,15 +19,27 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 __all__ = [
     "rms_norm", "init_rms_norm",
     "rope_frequencies", "apply_rope",
     "init_attention", "attention_ref", "attention",
     "init_mlp", "gated_mlp",
-    "softcap",
+    "softcap", "matmul", "einsum",
 ]
 
 Params = dict
+
+
+def matmul(a: jax.Array, b: jax.Array) -> jax.Array:
+    """The model's projections go through here (and ``einsum``), so a
+    precision control can round every product's inputs in one place."""
+    return jnp.matmul(a, b)
+
+
+def einsum(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    return jnp.einsum(spec, a, b)
 
 
 def init_rms_norm(d: int, dtype) -> Params:
@@ -113,15 +125,15 @@ def attention_ref(
     nkv = k.shape[2]
     group = nh // nkv
     qg = q.reshape(b, qlen, nkv, group, hd)
-    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) / jnp.sqrt(hd).astype(jnp.float32)
+    logits = einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
+                    k.astype(jnp.float32)) / jnp.sqrt(hd).astype(jnp.float32)
     logits = softcap(logits, logit_softcap)
     mask = _attn_mask(q_positions, kv_positions, window)
     if kv_valid is not None:
         mask = mask & kv_valid[None, :]
     logits = jnp.where(mask[None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32))
+    out = einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32))
     return out.reshape(b, qlen, nh, hd).astype(q.dtype)
 
 
@@ -163,8 +175,8 @@ def attention_blockwise(
     def block(carry, xs):
         acc, mx, lse = carry
         kc, vc, pc = xs
-        s = jnp.einsum("bqkgd,bskd->bkgqs", qg,
-                       kc.astype(jnp.float32)) * scale
+        s = einsum("bqkgd,bskd->bkgqs", qg,
+                   kc.astype(jnp.float32)) * scale
         s = softcap(s, logit_softcap)
         mask = q_positions[:, None] >= pc[None, :]
         if window is not None:
@@ -174,7 +186,7 @@ def attention_blockwise(
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(mx - m_new)
         lse = lse * corr + jnp.sum(p, axis=-1)
-        acc = acc * corr[..., None] + jnp.einsum(
+        acc = acc * corr[..., None] + einsum(
             "bkgqs,bskd->bkgqd", p, vc.astype(jnp.float32))
         return (acc, m_new, lse), None
 
@@ -190,25 +202,28 @@ def attention_blockwise(
     return out.astype(q.dtype)
 
 
+@tracing.scoped(tracing.ATTN)
 def attention(params: Params, x: jax.Array, positions: jax.Array, *,
               num_heads: int, num_kv_heads: int, head_dim: int,
-              rope_theta: float, window: int | None,
+              rope_theta: float | None, window: int | None,
               logit_softcap: float | None, qk_norm: bool, norm_eps: float,
               cache: dict | None = None, impl: str = "reference") -> tuple[jax.Array, dict | None]:
     """Full attention layer: qkv projection, rope, SDPA, out projection.
 
     ``cache`` (decode): {"k": (b, max_len, kv, hd), "v": ..., "len": int32}
     — the new token is written at index ``len`` and attends to the prefix.
+    ``rope_theta=None``: no positional encoding (jamba's attention).
     """
     b, s, d = x.shape
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
+    q = einsum("bsd,dhk->bshk", x, params["wq"])
+    k = einsum("bsd,dhk->bshk", x, params["wk"])
+    v = einsum("bsd,dhk->bshk", x, params["wv"])
     if qk_norm:
         q = rms_norm(params["q_norm"], q, norm_eps)
         k = rms_norm(params["k_norm"], k, norm_eps)
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    if rope_theta is not None:
+        q = apply_rope(q, positions, rope_theta)
+        k = apply_rope(k, positions, rope_theta)
 
     if cache is not None and s > 1:
         # Prefill into a fresh cache: attend among the new tokens exactly,
@@ -261,7 +276,7 @@ def attention(params: Params, x: jax.Array, positions: jax.Array, *,
                                 logit_softcap)
         new_cache = None
 
-    y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
+    y = einsum("bshk,hkd->bsd", out, params["wo"])
     return y, new_cache
 
 
@@ -279,6 +294,7 @@ def init_mlp(key, d_model: int, d_ff: int, dtype) -> Params:
     }
 
 
+@tracing.scoped(tracing.FFN)
 def gated_mlp(params: Params, x: jax.Array) -> jax.Array:
-    gate = jax.nn.silu(x @ params["w_gate"])
-    return (gate * (x @ params["w_up"])) @ params["w_down"]
+    gate = jax.nn.silu(matmul(x, params["w_gate"]))
+    return matmul(gate * matmul(x, params["w_up"]), params["w_down"])

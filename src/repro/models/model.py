@@ -52,16 +52,17 @@ def _init_layer(cfg: ArchConfig, spec: LayerSpec, key) -> dict:
                          "post_norm": L.init_rms_norm(cfg.d_model, dt)}
     if spec.mixer == "attn":
         p["attn"] = L.init_attention(
-            keys[0], cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+            keys[0], cfg.d_model, cfg.held_heads, cfg.held_kv_heads,
             cfg.resolved_head_dim, cfg.qk_norm, dt)
     elif spec.mixer == "mamba":
         p["mamba"] = Mb.init_mamba(keys[0], cfg.d_model, cfg.mamba_d_state,
-                                   cfg.mamba_d_conv, cfg.mamba_expand, dt)
+                                   cfg.mamba_d_conv, cfg.held_d_inner,
+                                   cfg.resolved_dt_rank, dt)
     elif spec.mixer == "rwkv":
         p["rwkv"] = Rk.init_rwkv_block(keys[0], cfg.d_model,
                                        cfg.rwkv_head_size, dt, cfg.d_ff)
     if spec.ffn == "dense" and spec.mixer != "rwkv":
-        p["mlp"] = L.init_mlp(keys[1], cfg.d_model, cfg.d_ff, dt)
+        p["mlp"] = L.init_mlp(keys[1], cfg.d_model, cfg.held_d_ff, dt)
     elif spec.ffn == "moe":
         p["moe"] = Moe.init_moe(keys[1], cfg.d_model, cfg.d_ff,
                                 cfg.num_experts, dt)
@@ -69,24 +70,25 @@ def _init_layer(cfg: ArchConfig, spec: LayerSpec, key) -> dict:
 
 
 def init_params(cfg: ArchConfig, key, with_head: bool = False) -> dict:
-    """Backbone parameters; period params stacked on leading axis."""
+    """Backbone parameters: ``layers`` a list of the period's runs
+    (``ArchConfig.layer_runs``), each leaf (periods, run length, ...)."""
     cfg.validate()
     dt = _dtype(cfg)
-    pattern = cfg.layer_pattern()
     n_periods = cfg.num_periods()
     k_embed, k_layers, k_head, k_front = jax.random.split(key, 4)
 
     params: dict[str, Any] = {
-        "embed": (jax.random.normal(k_embed, (cfg.vocab_size, cfg.d_model))
+        "embed": (jax.random.normal(k_embed, (cfg.held_vocab, cfg.d_model))
                   * (1.0 / jnp.sqrt(cfg.d_model))).astype(dt),
         "final_norm": L.init_rms_norm(cfg.d_model, dt),
     }
 
     def init_period(k):
-        ks = jax.random.split(k, len(pattern))
-        return [
-            _init_layer(cfg, spec, ks[i]) for i, spec in enumerate(pattern)
-        ]
+        runs = cfg.layer_runs()
+        ks = jax.random.split(k, len(runs))
+        return [jax.vmap(lambda kk, spec=spec: _init_layer(cfg, spec, kk))(
+                    jax.random.split(ks[i], n))
+                for i, (spec, n) in enumerate(runs)]
 
     period_keys = jax.random.split(k_layers, n_periods)
     stacked = jax.vmap(init_period)(period_keys)
@@ -103,8 +105,8 @@ def init_params(cfg: ArchConfig, key, with_head: bool = False) -> dict:
 
 
 def init_head(cfg: ArchConfig, key) -> jax.Array:
-    """The inner-variable readout head y (d_model, vocab)."""
-    return (jax.random.normal(key, (cfg.d_model, cfg.vocab_size))
+    """The inner-variable readout head y (d_model, vocab held here)."""
+    return (jax.random.normal(key, (cfg.d_model, cfg.held_vocab))
             * (1.0 / jnp.sqrt(cfg.d_model))).astype(_dtype(cfg))
 
 
@@ -126,8 +128,9 @@ def _apply_layer(cfg: ArchConfig, spec: LayerSpec, p: dict, x: jax.Array,
             window = cfg.local_window
         out, new_attn = L.attention(
             p["attn"], h, positions,
-            num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta,
+            num_heads=cfg.held_heads, num_kv_heads=cfg.held_kv_heads,
+            head_dim=cfg.resolved_head_dim,
+            rope_theta=cfg.rope_theta if cfg.use_rope else None,
             window=window, logit_softcap=cfg.attn_logit_softcap,
             qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps,
             cache=None if cache is None else cache["attn"], impl=impl)
@@ -136,12 +139,14 @@ def _apply_layer(cfg: ArchConfig, spec: LayerSpec, p: dict, x: jax.Array,
     elif spec.mixer == "mamba":
         if cache is None:
             out = Mb.mamba_block(p["mamba"], h,
-                                 seq_chunk=cfg.mamba_seq_chunk or None)
+                                 seq_chunk=cfg.mamba_seq_chunk or None,
+                                 eps=cfg.norm_eps)
         elif h.shape[1] > 1:  # prefill into a fresh cache
-            out, st = Mb.mamba_prefill(p["mamba"], h)
+            out, st = Mb.mamba_prefill(p["mamba"], h, eps=cfg.norm_eps)
             new_cache = {**cache, "mamba": st}
         else:
-            out, st = Mb.mamba_decode_step(p["mamba"], h, cache["mamba"])
+            out, st = Mb.mamba_decode_step(p["mamba"], h, cache["mamba"],
+                                           eps=cfg.norm_eps)
             new_cache = {**cache, "mamba": st}
     elif spec.mixer == "rwkv":
         if cache is None:
@@ -186,9 +191,16 @@ def _apply_layer(cfg: ArchConfig, spec: LayerSpec, p: dict, x: jax.Array,
     return x + out, new_cache, aux
 
 
+def _embed(cfg: ArchConfig, params: dict, tokens: jax.Array) -> jax.Array:
+    x = params["embed"][tokens]
+    if cfg.scale_embeddings:
+        x = x * jnp.sqrt(float(cfg.d_model)).astype(_dtype(cfg))
+    return x
+
+
 def _embed_inputs(cfg: ArchConfig, params: dict, tokens: jax.Array,
                   prefix_embed: jax.Array | None) -> jax.Array:
-    x = params["embed"][tokens] * jnp.sqrt(float(cfg.d_model)).astype(_dtype(cfg))
+    x = _embed(cfg, params, tokens)
     if prefix_embed is not None:
         proj = params.get("frontend_proj")
         pre = prefix_embed.astype(x.dtype)
@@ -212,30 +224,43 @@ def features(cfg: ArchConfig, params: dict, tokens: jax.Array,
     """
     x = _embed_inputs(cfg, params, tokens, prefix_embed)
     positions = jnp.arange(x.shape[1], dtype=jnp.int32)
-    pattern = cfg.layer_pattern()
 
     def constrain(h):
         if act_spec is None:
             return h
         return jax.lax.with_sharding_constraint(h, act_spec)
 
+    def layer_fn(spec):
+        def apply(p, h):
+            h, _, a = _apply_layer(cfg, spec, p, h, positions, impl,
+                                   moe_impl=moe_impl)
+            return h, a
+        # remat per layer: a backward holds one layer's activations
+        return jax.checkpoint(apply) if remat else apply
+
+    runs = cfg.layer_runs()
+    layer_fns = [layer_fn(spec) for spec, _ in runs]
+
+    def run_body(fn):
+        def body(carry, p):
+            h, a = fn(p, carry[0])
+            return (h, carry[1] + a), None
+        return body
+
     def period_body(carry, period_params):
+        for fn, run_params in zip(layer_fns, period_params):
+            carry, _ = jax.lax.scan(run_body(fn), carry, run_params)
         h, aux = carry
-        for i, spec in enumerate(pattern):
-            h, _, a = _apply_layer(cfg, spec, period_params[i], h,
-                                   positions, impl, moe_impl=moe_impl)
-            aux = aux + a
         return (constrain(h), aux), None
 
-    body = jax.checkpoint(period_body) if remat else period_body
     carry0 = (constrain(x), jnp.zeros((), jnp.float32))
-    (x, aux), _ = jax.lax.scan(body, carry0, params["layers"])
+    (x, aux), _ = jax.lax.scan(period_body, carry0, params["layers"])
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     return x, aux
 
 
 def head_logits(cfg: ArchConfig, head: jax.Array, feats: jax.Array) -> jax.Array:
-    logits = feats @ head
+    logits = L.matmul(feats, head)
     return L.softcap(logits, cfg.final_logit_softcap)
 
 
@@ -283,16 +308,15 @@ def _init_layer_cache(cfg: ArchConfig, spec: LayerSpec, batch: int,
         # long_500k sub-quadratic in memory.
         size = max_len if window is None else min(max_len, window)
         cache["attn"] = {
-            "k": jnp.zeros((batch, size, cfg.num_kv_heads,
+            "k": jnp.zeros((batch, size, cfg.held_kv_heads,
                             cfg.resolved_head_dim), dtype=dt),
-            "v": jnp.zeros((batch, size, cfg.num_kv_heads,
+            "v": jnp.zeros((batch, size, cfg.held_kv_heads,
                             cfg.resolved_head_dim), dtype=dt),
             "len": jnp.zeros((), jnp.int32),
         }
     elif spec.mixer == "mamba":
         cache["mamba"] = Mb.init_mamba_state(
-            batch, cfg.d_model, cfg.mamba_d_state, cfg.mamba_d_conv,
-            cfg.mamba_expand, dt)
+            batch, cfg.held_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv, dt)
     elif spec.mixer == "rwkv":
         cache["rwkv"] = Rk.init_rwkv_state(batch, cfg.d_model,
                                            cfg.rwkv_head_size)
@@ -332,17 +356,20 @@ def decode_step(cfg: ArchConfig, params: dict, head: jax.Array | None,
 
     Returns (logits (batch, s, vocab), new_cache).
     """
-    x = params["embed"][token] * jnp.sqrt(float(cfg.d_model)).astype(_dtype(cfg))
+    x = _embed(cfg, params, token)
     positions = position[None] if position.ndim == 0 else position
+    # (run, index in the run) of each layer of the period, in order
+    layers = [(r, j) for r, (_, n) in enumerate(cfg.layer_runs())
+              for j in range(n)]
     pattern = cfg.layer_pattern()
 
     def period_body(h, scanned):
         period_params, period_cache = scanned
         new_caches = []
-        for i, spec in enumerate(pattern):
-            h, nc, _ = _apply_layer(cfg, spec, period_params[i], h,
-                                    positions, impl, cache=period_cache[i],
-                                    moe_impl="exact")
+        for i, (spec, (r, j)) in enumerate(zip(pattern, layers)):
+            p = jax.tree_util.tree_map(lambda l: l[j], period_params[r])
+            h, nc, _ = _apply_layer(cfg, spec, p, h, positions, impl,
+                                    cache=period_cache[i], moe_impl="exact")
             new_caches.append(nc)
         return h, new_caches
 

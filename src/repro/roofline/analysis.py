@@ -75,10 +75,12 @@ def _moe_params(cfg: ArchConfig) -> int:
 
 def _mamba_params(cfg: ArchConfig) -> int:
     d_in = cfg.mamba_expand * cfg.d_model
+    r, n = cfg.resolved_dt_rank, cfg.mamba_d_state
     return (cfg.d_model * 2 * d_in                 # in_proj
-            + cfg.mamba_d_conv * d_in              # conv
-            + d_in * (2 * cfg.mamba_d_state + 1)   # B, C, dt_raw
-            + d_in * (cfg.mamba_d_state + 3)       # A, dt proj, D, bias
+            + (cfg.mamba_d_conv + 1) * d_in        # conv + bias
+            + d_in * (r + 2 * n) + r + 2 * n       # x_proj, dt/B/C norms
+            + r * d_in + d_in                      # dt_proj + bias
+            + d_in * (n + 1)                       # A, D
             + d_in * cfg.d_model)                  # out_proj
 
 

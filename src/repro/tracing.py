@@ -29,11 +29,17 @@ EQ11 = "repro.eq11"          # the eq.-11 metric; also its host span
 MIX = "repro.mix"            # the consensus combine(s) of Steps 1 and 3
 LOCAL_GRAD = "repro.local_grad"  # Step 2: the agents' local gradients
 HYPERGRAD = "repro.hypergrad"    # the inverse-Hessian solve of eq. (5)
+# LM backbone layers, inside local_grad (and hypergrad for lm_head)
+MAMBA = "repro.mamba"        # Mamba mixer: in_proj, conv, x_proj, scan, gate
+ATTN = "repro.attn"          # attention mixer: projections and softmax
+FFN = "repro.ffn"            # gated feed-forward
+LM_HEAD = "repro.lm_head"    # chunked CE and the head-space Neumann terms
 # host spans
 RUN = "repro.run"            # SolverBase.run's dispatch
 
 PHASES = (STEP, INIT, EQ11)
 LAYERS = (MIX, LOCAL_GRAD, HYPERGRAD)
+LM_LAYERS = (MAMBA, ATTN, FFN, LM_HEAD)
 
 _COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                    "/jax/core/compile/backend_compile_duration")

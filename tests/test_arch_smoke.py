@@ -41,6 +41,7 @@ def test_full_config_matches_assignment(arch):
         "paligemma-3b": (18, 2048, 8, 1, 16384, 257216),
         "dbrx-132b": (40, 6144, 48, 8, 10752, 100352),
         "llama3.2-3b": (28, 3072, 24, 8, 8192, 128256),
+        "jamba2-3b": (28, 2560, 20, 1, 8192, 65536),
     }[arch]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff, cfg.vocab_size)

@@ -13,7 +13,7 @@ from repro.configs import get_config
 from repro.models import model as M
 
 ARCHS = ["llama3.2-3b", "mixtral-8x7b", "rwkv6-3b",
-         "jamba-1.5-large-398b", "gemma2-2b", "qwen3-14b"]
+         "jamba-1.5-large-398b", "jamba2-3b", "gemma2-2b", "qwen3-14b"]
 
 
 @pytest.mark.parametrize("arch", ARCHS)
